@@ -33,6 +33,17 @@ def get_smoke(name: str) -> ModelConfig:
     return mod.smoke_config()
 
 
+def is_smoke(name: str, cfg: ModelConfig) -> bool:
+    """Is ``cfg`` the reduced (smoke) config of registry arch ``name``?"""
+    return name in _ARCH_MODULES and cfg == get_smoke(name)
+
+
+def for_plan(plan) -> ModelConfig:
+    """The config an ``ExecutionPlan`` was built on: the reduced one for
+    smoke plans, the published widths otherwise."""
+    return get_smoke(plan.arch) if plan.smoke else get(plan.arch)
+
+
 def paper_model(name: str) -> ModelConfig:
     from repro.configs import paper_models
 

@@ -1,7 +1,5 @@
 """StarTrail core: concentric-ring sequence parallelism (the paper's contribution)."""
 
-from repro import compat as _compat  # installs jax shims; keep first
-
 from repro.core.combine import combine_pair
 from repro.core.ring_attention import ring_attention
 from repro.core.startrail import (

@@ -18,8 +18,6 @@ The full contract (rule-set names, semantics, on-disk layout) is documented
 in ``docs/ARCHITECTURE.md``.
 """
 
-from repro import compat as _compat  # installs jax shims; keep first
-
 from repro.dist import checkpoint, elastic, meshes, sharding
 
 __all__ = ["checkpoint", "elastic", "meshes", "sharding"]

@@ -18,7 +18,6 @@ Public surface:
                            repro.gateway.PrefixCache is attached)
 """
 
-from repro import compat as _compat  # noqa: F401  (jax shims)
 from repro.engine.engine import (Engine, EngineConfig, EngineMetrics,
                                  build_engine)
 from repro.engine.paged_cache import PagePool

@@ -215,10 +215,11 @@ class Engine:
                  eng: EngineConfig = EngineConfig(), params=None, mesh=None,
                  registry: Optional[obs.Registry] = None,
                  labels: Optional[Dict[str, str]] = None,
-                 tracer: Optional[obs.Tracer] = None):
+                 tracer: Optional[obs.Tracer] = None, seed: int = 0):
         import jax
         import jax.numpy as jnp
         import dataclasses as dc
+        from jax.sharding import NamedSharding
 
         from repro.train import step as train_step
 
@@ -255,9 +256,13 @@ class Engine:
                         st_cfg=dc.replace(rt.st_cfg, seq_scheme="contiguous"))
         self.rt = rt
         self.kernel_impl = plan.kernel_impl
-        self.params = model.init(jax.random.PRNGKey(0)) if params is None \
-            else params
         self._param_specs = model.partition(run_cfg.sharding_rules)
+        # params live in the layout the step islands read them in: created
+        # there from ``seed``, or moved there once if the caller built them
+        param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                self._param_specs)
+        self.params = (model.init_sharded(param_sh, seed) if params is None
+                       else jax.device_put(params, param_sh))
         self._pool_part = paged_cache.pool_partition(cfg)
         self._sc = sampling_lib.SamplingConfig(max_top_k=eng.max_top_k)
         self._prefill_base = math.lcm(self.sp, eng.page_size)
@@ -979,6 +984,5 @@ def build_engine(arch: str, *, smoke: bool = True, c: Optional[int] = 1,
             cfg, arch=arch, n_devices=len(jax.devices()), data=data, c=c,
             decode_batch=eng.max_slots, page_size=eng.page_size,
             max_len=eng.max_len, mesh_kind="local", kernel_impl=kernel)
-    if params is None:
-        params = model.init(jax.random.PRNGKey(init_seed))
-    return Engine(model, plan, eng, params, registry=registry, tracer=tracer)
+    return Engine(model, plan, eng, params, registry=registry, tracer=tracer,
+                  seed=init_seed)

@@ -48,19 +48,16 @@ class EngineHost:
         from repro.plan import ExecutionPlan
 
         plan = ExecutionPlan.from_dict(spec["plan"])
-        cfg = (arch_registry.get_smoke(plan.arch)
-               if plan.mesh_kind == "local" else arch_registry.get(plan.arch))
+        cfg = arch_registry.for_plan(plan)
         eng_kw = dict(spec.get("eng") or {})
         if spec.get("prefill_chunk"):
             eng_kw["prefill_chunk"] = spec["prefill_chunk"]
         self.registry = obs.Registry()
         self.tracer = obs.Tracer(enabled=bool(spec.get("trace")))
         model = build_model(cfg)
-        import jax
-
-        params = model.init(jax.random.PRNGKey(int(spec.get("init_seed", 0))))
-        self.engine = Engine(model, plan, EngineConfig(**eng_kw), params,
-                             registry=self.registry, tracer=self.tracer)
+        self.engine = Engine(model, plan, EngineConfig(**eng_kw),
+                             registry=self.registry, tracer=self.tracer,
+                             seed=int(spec.get("init_seed", 0)))
         self._reported: set = set()
 
     # ---- boundary calls --------------------------------------------------

@@ -13,7 +13,6 @@ Public surface:
   block_hashes            — the chain hash over token pages
 """
 
-from repro import compat as _compat  # noqa: F401  (jax shims)
 from repro.gateway.gateway import Gateway, build_gateway, replica_meshes
 from repro.gateway.prefix_cache import PrefixCache, block_hashes
 from repro.gateway.router import Router

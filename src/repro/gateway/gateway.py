@@ -83,9 +83,8 @@ class Gateway:
 
     def __init__(self, model, plan, eng: EngineConfig = EngineConfig(),
                  params=None, registry: Optional[obs.Registry] = None,
-                 tracer: Optional[obs.Tracer] = None, plans=None):
-        import jax
-
+                 tracer: Optional[obs.Tracer] = None, plans=None,
+                 seed: int = 0):
         if plans:
             self.plans = list(plans)
             plan = plan if plan is not None else self.plans[0]
@@ -124,8 +123,6 @@ class Gateway:
         # under distinguishing {replica=i} labels
         self.registry = registry if registry is not None else obs.Registry()
         self.tracer = tracer if tracer is not None else obs.NULL_TRACER
-        if params is None:
-            params = model.init(jax.random.PRNGKey(0))
         if self.replicas == 1:
             meshes = [plan.build_mesh()]
         else:
@@ -133,7 +130,7 @@ class Gateway:
         self.engines: List[Engine] = [
             Engine(model, p, eng, params, mesh=m,
                    registry=self.registry, labels={"replica": str(i)},
-                   tracer=self.tracer)
+                   tracer=self.tracer, seed=seed)
             for i, (p, m) in enumerate(zip(self.plans, meshes))]
         self.cfg = self.engines[0].cfg
         self.router = Router(self.engines,
@@ -445,7 +442,5 @@ def build_gateway(arch: str, *, smoke: bool = True, c: Optional[int] = 1,
                 max_len=eng.max_len, mesh_kind="local", kernel_impl=kernel,
                 replicas=replicas, prefix_cache=prefix_cache,
                 host_tier_bytes=host_tier_bytes)
-    if params is None:
-        params = model.init(jax.random.PRNGKey(init_seed))
     return Gateway(model, plan, eng, params, registry=registry,
-                   tracer=tracer, plans=plans)
+                   tracer=tracer, plans=plans, seed=init_seed)

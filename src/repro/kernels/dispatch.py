@@ -27,11 +27,9 @@ for the entry points:
 ``jax.default_backend()`` is TPU, ``'ref'`` otherwise — the rule
 ``plan.make_plan`` applies to unset ``block_impl`` / ``kernel_impl`` knobs.
 
-The Pallas *training* block kernels take shared ``(S,)`` position vectors;
-calls with *batched* ``(B, S)`` positions (per-sequence cache lengths) run
-the scalar-prefetch ragged kernels — ``kernels/ragged_prefill.py`` forward
-and the ragged ``flash_attention_bwd`` path backward — so neither
-direction falls back to the reference any more. The fallback *accounting*
+The Pallas block kernels take shared ``(S,)`` position vectors (training)
+and *batched* ``(B, S)`` positions (per-sequence cache lengths) alike, so
+neither direction falls back to the reference. The fallback *accounting*
 stays: any future pallas->ref fallback must go through
 ``_note_fallback`` so it is counted per entry point
 (``pallas_fallbacks()``) and logged once, assertable in tests (the counter
@@ -128,10 +126,6 @@ def resolve_impl(impl: Optional[str] = None) -> str:
     return impl
 
 
-def _batched_positions(*pos) -> bool:
-    return any(jnp.ndim(p) > 1 for p in pos)
-
-
 # ---------------------------------------------------------------------------
 # ring-step block compute (training hot spot)
 # ---------------------------------------------------------------------------
@@ -140,16 +134,9 @@ def block_fwd(q, k, v, pos_q, pos_k, *, causal=True, window=None, scale=None,
               prefix_len=None, impl="ref") -> Tuple[jax.Array, jax.Array]:
     """Masked (Q block x K/V block) attention -> (o, lse) partials."""
     if impl == "pallas":
-        if not _batched_positions(pos_q, pos_k):
-            from repro.kernels import ops as _ops
+        from repro.kernels import ops as _ops
 
-            return _ops.flash_attention_fwd(
-                q, k, v, pos_q, pos_k, causal=causal, window=window,
-                scale=scale, prefix_len=prefix_len)
-        # batched (B, S) positions: the scalar-prefetch ragged kernel
-        from repro.kernels import ragged_prefill as _ragged
-
-        return _ragged.ragged_prefill_fwd(
+        return _ops.flash_attention_fwd(
             q, k, v, pos_q, pos_k, causal=causal, window=window,
             scale=scale, prefix_len=prefix_len)
     return _ref.block_attention(
@@ -163,30 +150,26 @@ def block_fwd_merge(q, k, v, o_acc, lse_acc, pos_q, pos_k, *, causal=True,
     """One ring step: block attention merged into the running accumulator.
 
     Semantically ``combine_pair(o_acc, lse_acc, *block_fwd(...))``. The
-    'pallas' path with shared positions fuses the combine into the flash
-    kernel epilogue, saving the extra HBM pass over the f32 accumulator;
-    every other path keeps the explicit two-step form (the oracle).
+    'pallas' path fuses the combine into the flash kernel epilogue, saving
+    the extra HBM pass over the f32 accumulator; 'ref' keeps the explicit
+    two-step form (the oracle).
     """
-    if impl == "pallas" and not _batched_positions(pos_q, pos_k):
+    if impl == "pallas":
         from repro.kernels import ops as _ops
 
         return _ops.flash_attention_fwd(
             q, k, v, pos_q, pos_k, o_acc=o_acc, lse_acc=lse_acc,
             causal=causal, window=window, scale=scale,
             prefix_len=prefix_len)
-    o_s, lse_s = block_fwd(q, k, v, pos_q, pos_k, causal=causal,
-                           window=window, scale=scale,
-                           prefix_len=prefix_len, impl=impl)
+    o_s, lse_s = _ref.block_attention(q, k, v, pos_q, pos_k, causal=causal,
+                                      window=window, scale=scale,
+                                      prefix_len=prefix_len)
     return _combine_pair(o_acc, lse_acc, o_s, lse_s)
 
 
 def block_bwd(q, k, v, do, lse, delta, pos_q, pos_k, *, causal=True,
               window=None, scale=None, prefix_len=None, impl="ref"):
-    """Flash backward for one block pair -> (dq, dk, dv) in float32.
-
-    Batched (B, S) positions run the scalar-prefetch ragged backward
-    kernels — no pallas->ref fallback on this entry point any more.
-    """
+    """Flash backward for one block pair -> (dq, dk, dv) in float32."""
     if impl == "pallas":
         from repro.kernels import ops as _ops
 

@@ -7,19 +7,34 @@ across the innermost (K/V-block) grid dimension, and fully-masked tiles are
 skipped with ``pl.when`` using the position metadata (this is what makes
 zigzag/causal and sliding-window cheap inside a ring step).
 
-Layouts match ``repro.kernels.ref``:
+Layouts at the API match ``repro.kernels.ref``:
     q (B, Sq, Hq, D); k, v (B, Sk, Hkv, D); o (B, Sq, Hq, D); lse (B, Hq, Sq)
-GQA is native: the K/V block index maps divide the query-head index by
-G = Hq // Hkv, so K/V tiles are never materialised per query head.
+    pos_q (Sq,) or (B, Sq); pos_k (Sk,) or (B, Sk)
+Inside the wrappers the heads move ahead of the sequence, ``(B, H, S, D)``,
+so every tile is a ``(block, D)`` slab whose last two dimensions the TPU
+lowering accepts (block a multiple of 8, D the full head dim). Per-row
+statistics travel as ``(B, H, S, 1)`` columns, query positions as
+``(B, S, 1)`` columns and key positions as ``(B, 1, S)`` rows, so the tile
+mask is a broadcast compare. GQA is native: the K/V block index maps divide
+the query-head index by G = Hq // Hkv.
+
+Shared ``(S,)`` positions (training) and per-row ``(B, S)`` positions (the
+engine's ragged serving buckets, where an unfilled key slot is pushed past
+every query) run the same kernels. Tile liveness — may this (Q, K/V) tile
+hold any visible entry — is computed once per call from per-tile position
+bounds into a table that rides in SMEM as a scalar-prefetch operand, so a
+skipped tile costs no FLOPs. Rows whose every key is masked finalise to
+``(o=0, lse=-inf)``, exact under ``core.combine.combine_pair``.
 
 Validated in ``interpret=True`` mode on CPU against ``ref.py``
-(tests/test_kernels.py sweeps shapes/dtypes); compiled path targets TPU.
+(tests/test_kernels.py, tests/test_prefill_kernels.py) and compiled for a
+described TPU v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,84 +45,155 @@ from repro.core.combine import NEG_INF
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+# TPU lowering: a tile's second-minor dim must be a multiple of 8 and its
+# minor dim a multiple of 128, unless the tile spans the whole dimension
+SUBLANES = 8
+LANES = 128
 
 
 def _mask_tile(pos_q, pos_k, causal, window, prefix_len=None):
-    """(bq, bk) bool mask tile from position vectors; None = all visible."""
+    """(bq, bk) bool mask from a (bq, 1) query column and a (1, bk) key row;
+    None = all visible."""
     if not causal and window is None:
         return None
-    pq = pos_q[:, None]
-    pk = pos_k[None, :]
-    m = jnp.ones((pos_q.shape[0], pos_k.shape[0]), dtype=jnp.bool_)
+    m = None
     if causal:
-        cm = pk <= pq
+        m = pos_k <= pos_q
         if prefix_len is not None:
-            cm |= pk < prefix_len
-        m &= cm
+            m |= pos_k < prefix_len
     if window is not None:
-        wm = (pq - pk) < window
+        wm = (pos_q - pos_k) < window
         if not causal:
-            wm &= (pk - pq) < window
+            wm &= (pos_k - pos_q) < window
         if prefix_len is not None:
-            wm |= pk < prefix_len
-        m &= wm
+            wm |= pos_k < prefix_len
+        m = wm if m is None else m & wm
     return m
 
 
-def _tile_live(pos_q, pos_k, causal, window, prefix_len=None):
-    """Scalar: does this tile have any unmasked entry? (for pl.when skip)"""
-    live = jnp.bool_(True)
+def _tile_live(lo_q, hi_q, lo_k, hi_k, causal, window, prefix_len=None):
+    """Does a tile with these position bounds hold any unmasked entry?"""
+    live = jnp.ones(jnp.broadcast_shapes(jnp.shape(lo_q), jnp.shape(lo_k)),
+                    jnp.bool_)
     if causal:
-        live &= jnp.min(pos_k) <= jnp.max(pos_q)
+        live &= lo_k <= hi_q
     if window is not None:
-        live &= (jnp.min(pos_q) - jnp.max(pos_k)) < window
+        live &= (lo_q - hi_k) < window
         if not causal:
-            live &= (jnp.min(pos_k) - jnp.max(pos_q)) < window
+            live &= (lo_k - hi_q) < window
     if prefix_len is not None:
-        live |= jnp.min(pos_k) < prefix_len
+        live |= lo_k < prefix_len
     return live
 
 
-def choose_block(s: int, pref: int) -> int:
-    """Largest tile size <= pref dividing s (non-power-of-two rows tile
-    at their largest aligned divisor instead of raising)."""
+def choose_block(s: int, pref: int, align: int = 1) -> int:
+    """Largest tile size <= pref dividing s and a multiple of ``align``
+    (non-power-of-two rows tile at their largest aligned divisor instead of
+    raising); the whole dimension when no such divisor exists."""
     for d in range(min(pref, s), 0, -1):
-        if s % d == 0:
+        if s % d == 0 and d % align == 0:
             return d
     return s
+
+
+def _rows(pos, S):
+    """Positions as int32 (Bp, S) rows, Bp = 1 for a shared vector."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return pos.reshape(1, S) if pos.ndim == 1 else pos
+
+
+def _live_table(pq, pk, block_q, block_k, causal, window, prefix_len):
+    """Flat int32 ``(Bl * n_q * n_k,)`` tile-liveness table, Bl = 1 when
+    both position arrays are shared. Row-major over (b, iq, ik)."""
+    bq_rows, sq = pq.shape
+    bk_rows, sk = pk.shape
+    tq = pq.reshape(bq_rows, sq // block_q, block_q)
+    tk = pk.reshape(bk_rows, sk // block_k, block_k)
+    live = _tile_live(tq.min(-1)[:, :, None], tq.max(-1)[:, :, None],
+                      tk.min(-1)[:, None, :], tk.max(-1)[:, None, :],
+                      causal, window, prefix_len)
+    return live.astype(jnp.int32).reshape(-1)
+
+
+class _Layout:
+    """Tiles, grid sizes and index helpers shared by the fwd/bwd wrappers."""
+
+    def __init__(self, q, k, pos_q, pos_k, block_q, block_k, interpret,
+                 causal, window, prefix_len):
+        B, Sq, Hq, D = q.shape
+        _, Sk, Hkv, _ = k.shape
+        self.B, self.Sq, self.Sk, self.Hq, self.Hkv, self.D = \
+            B, Sq, Sk, Hq, Hkv, D
+        self.G = Hq // Hkv
+        # the key-position row tile is lane-minor, so it needs 128-alignment
+        self.block_q = choose_block(Sq, block_q, 1 if interpret else SUBLANES)
+        self.block_k = choose_block(Sk, block_k, 1 if interpret else LANES)
+        self.n_q, self.n_k = Sq // self.block_q, Sk // self.block_k
+        pq, pk = _rows(pos_q, Sq), _rows(pos_k, Sk)
+        self.pq_b, self.pk_b = pq.shape[0] > 1, pk.shape[0] > 1
+        self.live = _live_table(pq, pk, self.block_q, self.block_k, causal,
+                                window, prefix_len)
+        self.live_b = self.pq_b or self.pk_b
+        self.pos_q_col = pq[:, :, None]                  # (Bp, Sq, 1)
+        self.pos_k_row = pk[:, None, :]                  # (Bp, 1, Sk)
+
+    def live_index(self, b, iq, ik):
+        bl = b if self.live_b else 0
+        return (bl * self.n_q + iq) * self.n_k + ik
+
+    def pos_q_spec(self, iq_of):
+        """BlockSpec of the query-position column; ``iq_of(*grid)`` -> iq."""
+        pb = self.pq_b
+        return pl.BlockSpec(
+            (1, self.block_q, 1),
+            lambda b, *g: (b if pb else 0, iq_of(b, *g), 0))
+
+    def pos_k_spec(self, ik_of):
+        pb = self.pk_b
+        return pl.BlockSpec(
+            (1, 1, self.block_k),
+            lambda b, *g: (b if pb else 0, 0, ik_of(b, *g)))
+
+
+def _heads_major(x):
+    """(B, S, H, D) -> (B, H, S, D)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _compiler_params(interpret, n_parallel):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))}
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_accumulate(pos_q, pos_k, q_ref, k_ref, v_ref, acc_ref, m_ref,
-                    l_ref, *, causal, window, scale, prefix_len):
-    """One K/V tile's online-softmax update of the (acc, m, l) scratch."""
-    @pl.when(_tile_live(pos_q, pos_k, causal, window, prefix_len))
-    def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)   # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)   # (bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        mask = _mask_tile(pos_q, pos_k, causal, window, prefix_len)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
-        p = jnp.exp(s - m_safe[:, None])
-        if mask is not None:
-            p = p * mask
-        alpha = jnp.where(
-            m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+def _fwd_accumulate(pos_q, pos_k, q, k, v, acc_ref, m_ref, l_ref, *, causal,
+                    window, scale, prefix_len):
+    """One K/V tile's online-softmax update of the (acc, m, l) scratch.
+
+    q (bq, D), k/v (bk, D) f32; pos_q (bq, 1), pos_k (1, bk)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # (bq, bk)
+    mask = _mask_tile(pos_q, pos_k, causal, window, prefix_len)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[...]                                # (bq, 1)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
+    p = jnp.exp(s - m_safe)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_cur
 
 
 def _block_partial(acc_ref, m_ref, l_ref):
@@ -116,17 +202,26 @@ def _block_partial(acc_ref, m_ref, l_ref):
     l = l_ref[...]
     dead = m <= NEG_INF / 2
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_blk = acc_ref[...] / l_safe[:, None]
+    o_blk = acc_ref[...] / l_safe
     lse_blk = jnp.where(
         dead, NEG_INF, jnp.where(dead, 0.0, m) + jnp.log(l_safe))
     return o_blk, lse_blk
 
 
-def _fwd_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref,  # inputs
-                o_ref, lse_ref,                              # outputs
-                acc_ref, m_ref, l_ref,                       # scratch
-                *, causal, window, scale, prefix_len, n_k):
-    ik = pl.program_id(3)
+def _fwd_kernel(live_ref,                                   # scalar prefetch
+                pq_ref, pk_ref, q_ref, k_ref, v_ref, *refs, causal, window,
+                scale, prefix_len, lay, merge):
+    """Grid (B, Hq, n_q, n_k). With ``merge`` the ring-step combine is fused
+    into the epilogue: instead of writing the block partial and paying a
+    separate full-array ``combine_pair`` pass over the f32 accumulator, the
+    finalize reads the running ``(o_acc, lse_acc)`` tile and emits the
+    rescaled merge directly — the exact op sequence of
+    ``core.combine.combine_pair``, in-register."""
+    if merge:
+        o_acc_ref, lse_acc_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    b, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -134,46 +229,23 @@ def _fwd_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref,  # inputs
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    _fwd_accumulate(pos_q_ref[...], pos_k_ref[...], q_ref, k_ref, v_ref,
-                    acc_ref, m_ref, l_ref, causal=causal, window=window,
-                    scale=scale, prefix_len=prefix_len)
+    @pl.when(live_ref[lay.live_index(b, iq, ik)] != 0)
+    def _compute():
+        _fwd_accumulate(
+            pq_ref[0], pk_ref[0], q_ref[0, 0].astype(jnp.float32),
+            k_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32),
+            acc_ref, m_ref, l_ref, causal=causal, window=window, scale=scale,
+            prefix_len=prefix_len)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(ik == lay.n_k - 1)
     def _finalize():
         o_blk, lse_blk = _block_partial(acc_ref, m_ref, l_ref)
-        o_ref[0, :, 0, :] = o_blk.astype(o_ref.dtype)
-        lse_ref[0, 0, :] = lse_blk.astype(lse_ref.dtype)
-
-
-def _fwd_merge_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref,
-                      o_acc_ref, lse_acc_ref,                # running acc in
-                      o_ref, lse_ref,                        # merged acc out
-                      acc_ref, m_ref, l_ref,                 # scratch
-                      *, causal, window, scale, prefix_len, n_k):
-    """``_fwd_kernel`` with the ring-step combine fused into the epilogue.
-
-    Instead of writing the block partial and paying a separate full-array
-    ``combine_pair`` pass over the f32 accumulator, the finalize reads the
-    running ``(o_acc, lse_acc)`` tile and emits the rescaled merge directly
-    — the exact op sequence of ``core.combine.combine_pair``, in-register.
-    """
-    ik = pl.program_id(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    _fwd_accumulate(pos_q_ref[...], pos_k_ref[...], q_ref, k_ref, v_ref,
-                    acc_ref, m_ref, l_ref, causal=causal, window=window,
-                    scale=scale, prefix_len=prefix_len)
-
-    @pl.when(ik == n_k - 1)
-    def _finalize():
-        o_blk, lse_blk = _block_partial(acc_ref, m_ref, l_ref)
-        o_prev = o_acc_ref[0, :, 0, :].astype(jnp.float32)
-        lse_prev = lse_acc_ref[0, 0, :].astype(jnp.float32)
+        if not merge:
+            o_ref[0, 0] = o_blk.astype(o_ref.dtype)
+            lse_ref[0, 0] = lse_blk.astype(lse_ref.dtype)
+            return
+        o_prev = o_acc_ref[0, 0].astype(jnp.float32)
+        lse_prev = lse_acc_ref[0, 0].astype(jnp.float32)
         # combine_pair(o_prev, lse_prev, o_blk, lse_blk), op for op
         m2 = jnp.maximum(lse_prev, lse_blk)
         both_dead = m2 <= NEG_INF / 2
@@ -182,9 +254,9 @@ def _fwd_merge_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref,
         w2 = jnp.exp(lse_blk - m2_safe)
         denom = w1 + w2
         denom_safe = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0, :, 0, :] = ((w1[:, None] * o_prev + w2[:, None] * o_blk)
-                             / denom_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(
+        o_ref[0, 0] = ((w1 * o_prev + w2 * o_blk)
+                       / denom_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(
             both_dead, NEG_INF, m2_safe + jnp.log(denom_safe)
         ).astype(lse_ref.dtype)
 
@@ -201,235 +273,147 @@ def flash_attention_fwd(
 ) -> Tuple[jax.Array, jax.Array]:
     """Block flash attention -> (o, lse). Same semantics as ref.block_attention.
 
-    With ``(o_acc, lse_acc)`` — a running partial accumulator of shapes
-    ``(B, Sq, Hq, D)`` / ``(B, Hq, Sq)`` — the per-ring-step combine is
-    fused into the kernel epilogue: the result is
+    Positions are shared ``(S,)`` vectors or per-row ``(B, S)`` arrays
+    (ragged serving buckets). With ``(o_acc, lse_acc)`` — a running partial
+    accumulator of shapes ``(B, Sq, Hq, D)`` / ``(B, Hq, Sq)`` — the
+    per-ring-step combine is fused into the kernel epilogue: the result is
     ``combine_pair(o_acc, lse_acc, *flash_attention_fwd(...))`` without the
     separate full-array pass over the f32 accumulator.
     """
     if (o_acc is None) != (lse_acc is None):
         raise ValueError("o_acc and lse_acc must be passed together")
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
-    G = Hq // Hkv
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    if Sq % block_q or Sk % block_k:
-        raise ValueError(f"{Sq=} % {block_q=} or {Sk=} % {block_k=} != 0")
-    n_q, n_k = Sq // block_q, Sk // block_k
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-
-    grid = (B, Hq, n_q, n_k)
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    lay = _Layout(q, k, pos_q, pos_k, block_q, block_k, interpret, causal,
+                  window, prefix_len)
+    B, Sq, Hq, D, G = lay.B, lay.Sq, lay.Hq, lay.D, lay.G
+    bq, bk = lay.block_q, lay.block_k
     merge = o_acc is not None
-    kernel = functools.partial(
-        _fwd_merge_kernel if merge else _fwd_kernel, causal=causal,
-        window=window, scale=scale, prefix_len=prefix_len, n_k=n_k)
 
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-    in_specs = [
-        pl.BlockSpec((block_q,), lambda b, h, iq, ik: (iq,)),
-        pl.BlockSpec((block_k,), lambda b, h, iq, ik: (ik,)),
-        pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        pl.BlockSpec((1, block_k, 1, D),
-                     lambda b, h, iq, ik: (b, ik, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, D),
-                     lambda b, h, iq, ik: (b, ik, h // G, 0)),
-    ]
-    inputs = [pos_q, pos_k, q, k, v]
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, lv: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, iq, ik, lv: (b, h // G, ik, 0))
+    row_spec = pl.BlockSpec((1, 1, bq, 1),
+                            lambda b, h, iq, ik, lv: (b, h, iq, 0))
+    in_specs = [lay.pos_q_spec(lambda b, h, iq, ik, lv: iq),
+                lay.pos_k_spec(lambda b, h, iq, ik, lv: ik),
+                q_spec, kv_spec, kv_spec]
+    inputs = [lay.pos_q_col, lay.pos_k_row, _heads_major(q), _heads_major(k),
+              _heads_major(v)]
     if merge:
-        in_specs += [
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        ]
-        inputs += [o_acc.astype(jnp.float32), lse_acc.astype(jnp.float32)]
+        in_specs += [q_spec, row_spec]
+        inputs += [_heads_major(o_acc.astype(jnp.float32)),
+                   lse_acc.astype(jnp.float32)[..., None]]
 
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        ],
+        functools.partial(_fwd_kernel, causal=causal, window=window,
+                          scale=scale, prefix_len=prefix_len, lay=lay,
+                          merge=merge),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hq, lay.n_q, lay.n_k),
+            in_specs=in_specs,
+            out_specs=[q_spec, row_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
-        **params,
-    )(*inputs)
-    return o, lse
+        **_compiler_params(interpret, 3),
+    )(lay.live, *inputs)
+    return _heads_major(o), lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
-# backward: dq kernel (accumulate over K/V blocks)
+# backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_accumulate(pos_q, pos_k, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_acc, *, causal, window, scale,
-                       prefix_len):
-    @pl.when(_tile_live(pos_q, pos_k, causal, window, prefix_len))
+def _bwd_probs(pos_q, pos_k, q, k, lse, *, causal, window, scale,
+               prefix_len):
+    """True attention probabilities p (bq, bk) of one tile from the global
+    lse (bq, 1)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    mask = _mask_tile(pos_q, pos_k, causal, window, prefix_len)
+    if mask is not None:
+        # mask BEFORE exp: masked raw scores can exceed lse -> inf*0=NaN
+        s = jnp.where(mask, s, NEG_INF)
+    dead = lse <= NEG_INF / 2
+    p = jnp.exp(s - jnp.where(dead, 0.0, lse))
+    return jnp.where(dead, 0.0, p)
+
+
+def _bwd_tile(pq_ref, pk_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+              delta_ref, **kw):
+    """(q, k, do, p, ds) of one tile, f32."""
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    p = _bwd_probs(pq_ref[0], pk_ref[0], q, k,
+                   lse_ref[0, 0].astype(jnp.float32), **kw)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[0, 0].astype(jnp.float32)) * kw["scale"]
+    return q, k, do, p, ds
+
+
+def _bwd_dq_kernel(live_ref, pq_ref, pk_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, dq_acc, *, lay, **kw):
+    """Grid (B, Hq, n_q, n_k): dq accumulates over the K/V tiles."""
+    b, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(live_ref[lay.live_index(b, iq, ik)] != 0)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :].astype(jnp.float32)
-        delta = delta_ref[0, 0, :].astype(jnp.float32)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _mask_tile(pos_q, pos_k, causal, window, prefix_len)
-        if mask is not None:
-            # mask BEFORE exp: masked raw scores can exceed lse -> inf*0=NaN
-            s = jnp.where(mask, s, NEG_INF)
-        dead = lse <= NEG_INF / 2
-        p = jnp.exp(s - jnp.where(dead, 0.0, lse)[:, None])
-        p = jnp.where(dead[:, None], 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        _, k, _, _, ds = _bwd_tile(pq_ref, pk_ref, q_ref, k_ref, v_ref,
+                                   do_ref, lse_ref, delta_ref, **kw)
         dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
 
-
-def _bwd_dq_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dq_acc, *, causal, window,
-                   scale, prefix_len, n_k):
-    ik = pl.program_id(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    _bwd_dq_accumulate(pos_q_ref[...], pos_k_ref[...], q_ref, k_ref, v_ref,
-                       do_ref, lse_ref, delta_ref, dq_acc, causal=causal,
-                       window=window, scale=scale, prefix_len=prefix_len)
-
-    @pl.when(ik == n_k - 1)
+    @pl.when(ik == lay.n_k - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dq_ragged_kernel(pos_q_ref, pos_k_ref,          # scalar prefetch
-                          q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_acc, *, causal, window, scale,
-                          prefix_len, block_q, block_k, n_k):
-    """``_bwd_dq_kernel`` with per-row (B, S) positions from SMEM
-    (the ``ragged_prefill.py`` scalar-prefetch pattern)."""
-    b = pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def _bwd_dkv_kernel(live_ref, pq_ref, pk_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                    lay, **kw):
+    """Grid (B, Hkv, n_k, G * n_q), t = g * n_q + iq: dk/dv accumulate over
+    the query tiles of every query head in this K/V head's group."""
+    b, ik, t = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    n_t = lay.G * lay.n_q
 
-    @pl.when(ik == 0)
+    @pl.when(t == 0)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    pos_q = pos_q_ref[b, pl.ds(iq * block_q, block_q)]
-    pos_k = pos_k_ref[b, pl.ds(ik * block_k, block_k)]
-    _bwd_dq_accumulate(pos_q, pos_k, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_acc, causal=causal, window=window,
-                       scale=scale, prefix_len=prefix_len)
-
-    @pl.when(ik == n_k - 1)
-    def _finalize():
-        dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
-# backward: dk/dv kernel (accumulate over the G * n_q combined dimension)
-# ---------------------------------------------------------------------------
-
-def _bwd_dkv_accumulate(pos_q, pos_k, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_acc, dv_acc, *, causal, window, scale,
-                        prefix_len):
-    @pl.when(_tile_live(pos_q, pos_k, causal, window, prefix_len))
+    @pl.when(live_ref[lay.live_index(b, t % lay.n_q, ik)] != 0)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :].astype(jnp.float32)
-        delta = delta_ref[0, 0, :].astype(jnp.float32)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _mask_tile(pos_q, pos_k, causal, window, prefix_len)
-        if mask is not None:
-            # mask BEFORE exp: masked raw scores can exceed lse -> inf*0=NaN
-            s = jnp.where(mask, s, NEG_INF)
-        dead = lse <= NEG_INF / 2
-        p = jnp.exp(s - jnp.where(dead, 0.0, lse)[:, None])
-        p = jnp.where(dead[:, None], 0.0, p)
-        # dv += p^T do ; ds = p (do v^T - delta) ; dk += ds^T q
+        q, _, do, p, ds = _bwd_tile(pq_ref, pk_ref, q_ref, k_ref, v_ref,
+                                    do_ref, lse_ref, delta_ref, **kw)
+        # dv += p^T do ; dk += ds^T q
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
         dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
 
-
-def _bwd_dkv_kernel(pos_q_ref, pos_k_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, causal, window, scale, prefix_len, n_t):
-    it = pl.program_id(3)
-
-    @pl.when(it == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    _bwd_dkv_accumulate(pos_q_ref[...], pos_k_ref[...], q_ref, k_ref, v_ref,
-                        do_ref, lse_ref, delta_ref, dk_acc, dv_acc,
-                        causal=causal, window=window, scale=scale,
-                        prefix_len=prefix_len)
-
-    @pl.when(it == n_t - 1)
+    @pl.when(t == n_t - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _bwd_dkv_ragged_kernel(pos_q_ref, pos_k_ref,         # scalar prefetch
-                           q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                           window, scale, prefix_len, block_q, block_k,
-                           n_q, n_t):
-    """``_bwd_dkv_kernel`` with per-row (B, S) positions from SMEM."""
-    b = pl.program_id(0)
-    ik = pl.program_id(2)
-    it = pl.program_id(3)
-
-    @pl.when(it == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    pos_q = pos_q_ref[b, pl.ds((it % n_q) * block_q, block_q)]
-    pos_k = pos_k_ref[b, pl.ds(ik * block_k, block_k)]
-    _bwd_dkv_accumulate(pos_q, pos_k, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_acc, dv_acc, causal=causal,
-                        window=window, scale=scale, prefix_len=prefix_len)
-
-    @pl.when(it == n_t - 1)
-    def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(
@@ -445,180 +429,77 @@ def flash_attention_bwd(
     """Flash backward for one (Q x K/V) block pair using the global lse.
 
     Returns (dq, dk, dv) in float32 (shapes of q, k, v). Semantics match
-    ``ref.block_attention_bwd``. Batched ``(B, S)`` positions (per-row
-    cache lengths) route to the scalar-prefetch ragged kernels — the same
-    SMEM pattern as ``ragged_prefill.py`` — so serving backward paths no
-    longer fall back to the reference.
+    ``ref.block_attention_bwd``; shared ``(S,)`` and per-row ``(B, S)``
+    positions run the same kernels.
     """
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
-    G = Hq // Hkv
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-
-    if jnp.ndim(pos_q) > 1 or jnp.ndim(pos_k) > 1:
-        return _flash_attention_bwd_ragged(
-            q, k, v, do, lse, delta, pos_q, pos_k, causal=causal,
-            window=window, scale=scale, prefix_len=prefix_len,
-            block_q=block_q, block_k=block_k, interpret=interpret)
-
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    n_q, n_k = Sq // block_q, Sk // block_k
-
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    lay = _Layout(q, k, pos_q, pos_k, block_q, block_k, interpret, causal,
+                  window, prefix_len)
+    B, Sq, Sk, Hq, Hkv, D, G = (lay.B, lay.Sq, lay.Sk, lay.Hq, lay.Hkv,
+                                lay.D, lay.G)
+    bq, bk, n_q = lay.block_q, lay.block_k, lay.n_q
+    kw = dict(causal=causal, window=window, scale=scale,
+              prefix_len=prefix_len, lay=lay)
+    inputs = (lay.live, lay.pos_q_col, lay.pos_k_row, _heads_major(q),
+              _heads_major(k), _heads_major(v), _heads_major(do),
+              lse.astype(jnp.float32)[..., None],
+              delta.astype(jnp.float32)[..., None])
 
     # ---- dq: grid (B, Hq, n_q, n_k), accumulate over ik ----
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, lv: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, iq, ik, lv: (b, h // G, ik, 0))
+    row_spec = pl.BlockSpec((1, 1, bq, 1),
+                            lambda b, h, iq, ik, lv: (b, h, iq, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, window=window,
-                          scale=scale, prefix_len=prefix_len, n_k=n_k),
-        grid=(B, Hq, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda b, h, iq, ik: (iq,)),
-            pl.BlockSpec((block_k,), lambda b, h, iq, ik: (ik,)),
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-        **params,
-    )(pos_q, pos_k, q, k, v, do, lse, delta)
-
-    # ---- dk/dv: grid (B, Hkv, n_k, G * n_q); t = g * n_q + iq ----
-    n_t = G * n_q
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, window=window,
-                          scale=scale, prefix_len=prefix_len, n_t=n_t),
-        grid=(B, Hkv, n_k, n_t),
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda b, h, ik, t: (t % n_q,)),
-            pl.BlockSpec((block_k,), lambda b, h, ik, t: (ik,)),
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, ik, t: (b, t % n_q, h * G + t // n_q, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, t: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, t: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, ik, t: (b, t % n_q, h * G + t // n_q, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, ik, t: (b, h * G + t // n_q, t % n_q)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, ik, t: (b, h * G + t // n_q, t % n_q)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, t: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, t: (b, ik, h, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-        **params,
-    )(pos_q, pos_k, q, k, v, do, lse, delta)
-
-    return dq, dk, dv
-
-
-def _flash_attention_bwd_ragged(q, k, v, do, lse, delta, pos_q, pos_k, *,
-                                causal, window, scale, prefix_len, block_q,
-                                block_k, interpret):
-    """Backward with per-row (B, S) positions via scalar prefetch.
-
-    Mirrors ``ragged_prefill.ragged_prefill_fwd``: the position arrays ride
-    in SMEM ahead of the tile DMAs, each kernel instance slices its row's
-    window with ``pl.ds``, and tile liveness/skip comes from those slices.
-    Shared ``(S,)`` vectors are broadcast to ``(B, S)``.
-    """
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
-    G = Hq // Hkv
-    pos_q = jnp.asarray(pos_q, jnp.int32)
-    pos_k = jnp.asarray(pos_k, jnp.int32)
-    if pos_q.ndim == 1:
-        pos_q = jnp.broadcast_to(pos_q[None], (B, Sq))
-    if pos_k.ndim == 1:
-        pos_k = jnp.broadcast_to(pos_k[None], (B, Sk))
-    block_q = choose_block(Sq, block_q)
-    block_k = choose_block(Sk, block_k)
-    n_q, n_k = Sq // block_q, Sk // block_k
-
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-
-    q_spec = pl.BlockSpec((1, block_q, 1, D),
-                          lambda b, h, iq, ik, pq, pk: (b, iq, h, 0))
-    kv_spec = pl.BlockSpec((1, block_k, 1, D),
-                           lambda b, h, iq, ik, pq, pk: (b, ik, h // G, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q),
-                            lambda b, h, iq, ik, pq, pk: (b, h, iq))
-
-    # ---- dq: grid (B, Hq, n_q, n_k), accumulate over ik ----
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_ragged_kernel, causal=causal,
-                          window=window, scale=scale, prefix_len=prefix_len,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
+        functools.partial(_bwd_dq_kernel, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hq, n_q, n_k),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            num_scalar_prefetch=1,
+            grid=(B, Hq, n_q, lay.n_k),
+            in_specs=[lay.pos_q_spec(lambda b, h, iq, ik, lv: iq),
+                      lay.pos_k_spec(lambda b, h, iq, ik, lv: ik),
+                      q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
         interpret=interpret,
-        **params,
-    )(pos_q, pos_k, q, k, v, do, lse, delta)
+        **_compiler_params(interpret, 3),
+    )(*inputs)
 
     # ---- dk/dv: grid (B, Hkv, n_k, G * n_q); t = g * n_q + iq ----
-    n_t = G * n_q
     qg_spec = pl.BlockSpec(
-        (1, block_q, 1, D),
-        lambda b, h, ik, t, pq, pk: (b, t % n_q, h * G + t // n_q, 0))
-    kvg_spec = pl.BlockSpec((1, block_k, 1, D),
-                            lambda b, h, ik, t, pq, pk: (b, ik, h, 0))
+        (1, 1, bq, D),
+        lambda b, h, ik, t, lv: (b, h * G + t // n_q, t % n_q, 0))
+    kvg_spec = pl.BlockSpec((1, 1, bk, D),
+                            lambda b, h, ik, t, lv: (b, h, ik, 0))
     rowg_spec = pl.BlockSpec(
-        (1, 1, block_q),
-        lambda b, h, ik, t, pq, pk: (b, h * G + t // n_q, t % n_q))
+        (1, 1, bq, 1),
+        lambda b, h, ik, t, lv: (b, h * G + t // n_q, t % n_q, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_ragged_kernel, causal=causal,
-                          window=window, scale=scale, prefix_len=prefix_len,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
-                          n_t=n_t),
+        functools.partial(_bwd_dkv_kernel, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hkv, n_k, n_t),
-            in_specs=[qg_spec, kvg_spec, kvg_spec, qg_spec, rowg_spec,
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, lay.n_k, G * n_q),
+            in_specs=[lay.pos_q_spec(lambda b, h, ik, t, lv: t % n_q),
+                      lay.pos_k_spec(lambda b, h, ik, t, lv: ik),
+                      qg_spec, kvg_spec, kvg_spec, qg_spec, rowg_spec,
                       rowg_spec],
             out_specs=[kvg_spec, kvg_spec],
             scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), jnp.float32),
         ],
         interpret=interpret,
-        **params,
-    )(pos_q, pos_k, q, k, v, do, lse, delta)
+        **_compiler_params(interpret, 3),
+    )(*inputs)
 
-    return dq, dk, dv
+    return _heads_major(dq), _heads_major(dk), _heads_major(dv)

@@ -12,10 +12,12 @@ the ``BlockSpec`` index map DMAs each K/V page straight from the pool:
     cache_len      : (B,) int32                       the new token's position
     rank           : (1,) int32                       this shard's SP rank (traced)
 
-Grid ``(B, Hq, W)`` with the page dimension innermost; the online-softmax
-statistics (m, l, acc) live in VMEM scratch across the W steps, exactly as
-in the training kernel. GQA is native (the K/V index map divides the query
-head by G = Hq // Hkv). Pages that are unallocated (``table < 0``), fully
+Grid ``(B, W)`` with the page dimension innermost. Each step DMAs one whole
+page ``(page_size, Hkv, D)`` — its last two dims span the pool's, as the
+TPU lowering requires — and serves every head from it: the query heads are
+grouped by their K/V head, ``(B, Hkv, G, D)`` with G = Hq // Hkv, and a
+static loop over the K/V heads runs the online softmax whose statistics
+(m, l, acc) live in VMEM scratch across the W steps. Pages that are unallocated (``table < 0``), fully
 in the causal future, or fully outside the sliding window are skipped with
 ``pl.when`` — the skip test only reads prefetched scalars, so a skipped
 page costs no FLOPs.
@@ -49,9 +51,9 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
             q_ref, k_ref, v_ref,                        # inputs
             o_ref, lse_ref,                             # outputs
             acc_ref, m_ref, l_ref,                      # scratch
-            *, sp, page_size, window, scale, n_w):
+            *, sp, page_size, window, scale, n_w, n_kv):
     b = pl.program_id(0)
-    w = pl.program_id(2)
+    w = pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
@@ -69,27 +71,29 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (ps, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (1, ps)
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
         valid = pos <= cl
         if window is not None:
             valid &= (cl - pos) < window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]                              # (1,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
-        p = jnp.exp(s - m_safe[:, None]) * valid
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+        for hk in range(n_kv):                           # static head loop
+            q = q_ref[0, hk].astype(jnp.float32)         # (G, D)
+            k = k_ref[0, :, hk, :].astype(jnp.float32)   # (ps, D)
+            v = v_ref[0, :, hk, :].astype(jnp.float32)   # (ps, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, ps)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[hk]                           # (G, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
+            p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_ref[hk] = l_ref[hk] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[hk] = acc_ref[hk] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[hk] = m_cur
 
     @pl.when(w == n_w - 1)
     def _finalize():
@@ -97,8 +101,8 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
         l = l_ref[...]
         dead = m <= NEG_INF / 2
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(
             dead, NEG_INF, jnp.where(dead, 0.0, m) + jnp.log(l_safe)
         ).astype(lse_ref.dtype)
 
@@ -134,48 +138,53 @@ def paged_decode_attention(
 
     kernel = functools.partial(
         _kernel, sp=sp, page_size=page_size, window=window, scale=scale,
-        n_w=W)
+        n_w=W, n_kv=Hkv)
 
-    def page_idx(b, h, w, tbl, cl, rk):
+    def page_idx(b, w, tbl, cl, rk):
         # -1 (unallocated) clips to page 0; the kernel masks it via pl.when
         del cl, rk
-        return (jnp.maximum(tbl[b, w], 0), 0, h // G, 0)
+        return (jnp.maximum(tbl[b, w], 0), 0, 0, 0)
 
+    def row_idx(b, w, tbl, cl, rk):
+        del w, tbl, cl, rk
+        return (b, 0, 0, 0)
+
+    # every head of a row in one tile: the query heads grouped by their K/V
+    # head, (B, Hkv, G, D), and whole pages, whose last two dims (Hkv, D)
+    # span the pool's — one DMA per page serves all heads
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hq, W),
+        grid=(B, W),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, w, tbl, cl, rk: (b, 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D), page_idx),
-            pl.BlockSpec((1, page_size, 1, D), page_idx),
+            pl.BlockSpec((1, Hkv, G, D), row_idx),
+            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
+            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, w, tbl, cl, rk: (b, 0, h, 0)),
-            pl.BlockSpec((1, 1, 1),
-                         lambda b, h, w, tbl, cl, rk: (b, h, 0)),
+            pl.BlockSpec((1, Hkv, G, D), row_idx),
+            pl.BlockSpec((1, Hkv, G, 1), row_idx),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
         ],
     )
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary"))
 
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1, Hq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
         ],
         interpret=interpret,
         **params,
     )(table.astype(jnp.int32), cache_len.astype(jnp.int32),
-      jnp.asarray(rank, jnp.int32).reshape(1), q, pool_k, pool_v)
-    return o, lse
+      jnp.asarray(rank, jnp.int32).reshape(1), q.reshape(B, Hkv, G, D),
+      pool_k, pool_v)
+    return o.reshape(B, 1, Hq, D), lse.reshape(B, Hq, 1)

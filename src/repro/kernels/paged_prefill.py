@@ -16,9 +16,12 @@ page tile straight from the pool:
     cached_len     : (B,) int32         tokens already in the pool
     rank           : (1,) int32         this shard's SP rank (traced)
 
-Grid ``(B, Hq, n_q, W)`` with the page dimension innermost; the
-online-softmax statistics (m, l, acc) persist in VMEM scratch across the W
-steps. Pages that are unallocated (``table < 0``), entirely at or past
+Grid ``(B, n_q, W)`` with the page dimension innermost. Each step DMAs one
+whole page ``(page_size, Hkv, D)`` — its last two dims span the pool's, as
+the TPU lowering requires — and serves every head from it: the queries are
+heads-major ``(B, Hq, Sq, D)`` tiles, and static loops over the K/V heads
+and their G = Hq // Hkv query heads run the online softmax, whose
+statistics (m, l, acc) persist in VMEM scratch across the W steps. Pages that are unallocated (``table < 0``), entirely at or past
 ``cached_len`` (suffix pages being written this very call), or fully
 outside the sliding window are skipped with ``pl.when`` — the skip test
 reads only prefetched scalars, so a masked page costs no FLOPs and no
@@ -48,19 +51,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.combine import NEG_INF
-from repro.kernels.ragged_prefill import choose_block
+from repro.kernels.flash_attention import SUBLANES, choose_block
 
-DEFAULT_BLOCK_Q = 128
+# every head's (block_q, D) tile, accumulator and statistics share VMEM:
+# at 32 heads a 128-row tile overflows the 16 MiB scoped default
+DEFAULT_BLOCK_Q = 64
 
 
 def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
             q_ref, k_ref, v_ref,                        # inputs
             o_ref, lse_ref,                             # outputs
             acc_ref, m_ref, l_ref,                      # scratch
-            *, sp, page_size, window, scale, block_q, n_w):
+            *, sp, page_size, window, scale, block_q, n_w, n_kv, group):
     b = pl.program_id(0)
-    iq = pl.program_id(2)
-    w = pl.program_id(3)
+    iq = pl.program_id(1)
+    w = pl.program_id(2)
 
     @pl.when(w == 0)
     def _init():
@@ -80,12 +85,6 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (ps, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, ps)
         pos_k = base + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)                # (1, ps)
         valid = pos_k < cl                               # strict: prefix only
@@ -93,17 +92,29 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
             pos_q = cl + iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0)              # (bq, 1)
             valid = valid & ((pos_q - pos_k) < window)   # (bq, ps)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]                              # (bq,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
-        p = jnp.exp(s - m_safe[:, None]) * valid
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+        for hk in range(n_kv):                           # static head loops
+            k = k_ref[0, :, hk, :].astype(jnp.float32)   # (ps, D)
+            v = v_ref[0, :, hk, :].astype(jnp.float32)   # (ps, D)
+            for g in range(group):
+                h = hk * group + g
+                q = q_ref[0, h].astype(jnp.float32)      # (bq, D)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (bq, ps)
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[h]                        # (bq, 1)
+                m_cur = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
+                p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+                alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                                  jnp.exp(m_prev - m_safe))
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_cur
 
     @pl.when(w == n_w - 1)
     def _finalize():
@@ -111,8 +122,8 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
         l = l_ref[...]
         dead = m <= NEG_INF / 2
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(
             dead, NEG_INF, jnp.where(dead, 0.0, m) + jnp.log(l_safe)
         ).astype(lse_ref.dtype)
 
@@ -145,56 +156,60 @@ def paged_prefill_attention(
     W = table.shape[1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    block_q = choose_block(Sq, block_q)
-    n_q = Sq // block_q
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    block_q = choose_block(Sq, block_q, 1 if interpret else SUBLANES)
+    n_q = Sq // block_q
 
     kernel = functools.partial(
         _kernel, sp=sp, page_size=page_size, window=window, scale=scale,
-        block_q=block_q, n_w=W)
+        block_q=block_q, n_w=W, n_kv=Hkv, group=G)
 
-    def page_idx(b, h, iq, w, tbl, cl, rk):
+    def page_idx(b, iq, w, tbl, cl, rk):
         # -1 (unallocated) clips to page 0; the kernel masks it via pl.when
         del iq, cl, rk
-        return (jnp.maximum(tbl[b, w], 0), 0, h // G, 0)
+        return (jnp.maximum(tbl[b, w], 0), 0, 0, 0)
 
+    def tile_idx(b, iq, w, tbl, cl, rk):
+        del w, tbl, cl, rk
+        return (b, 0, iq, 0)
+
+    # one query tile of every head, heads-major (B, Hq, Sq, D), against
+    # whole pages, whose last two dims (Hkv, D) span the pool's — one DMA
+    # per page serves all heads
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hq, n_q, W),
+        grid=(B, n_q, W),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, w, tbl, cl, rk: (b, iq, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D), page_idx),
-            pl.BlockSpec((1, page_size, 1, D), page_idx),
+            pl.BlockSpec((1, Hq, block_q, D), tile_idx),
+            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
+            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, w, tbl, cl, rk: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, iq, w, tbl, cl, rk: (b, h, iq)),
+            pl.BlockSpec((1, Hq, block_q, D), tile_idx),
+            pl.BlockSpec((1, Hq, block_q, 1), tile_idx),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((Hq, block_q, D), jnp.float32),
+            pltpu.VMEM((Hq, block_q, 1), jnp.float32),
+            pltpu.VMEM((Hq, block_q, 1), jnp.float32),
         ],
     )
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
         **params,
     )(table.astype(jnp.int32), cached_len.astype(jnp.int32),
-      jnp.asarray(rank, jnp.int32).reshape(1), q, pool_k, pool_v)
-    return o, lse
+      jnp.asarray(rank, jnp.int32).reshape(1), jnp.swapaxes(q, 1, 2),
+      pool_k, pool_v)
+    return jnp.swapaxes(o, 1, 2), lse[..., 0]

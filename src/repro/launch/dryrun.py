@@ -131,8 +131,7 @@ def lower_cell(arch: str, shape_name: str, **plan_kw):
     if plan is not None:
         # replay: the plan carries the shape (incl. non-registry ones like
         # 'smoke') and whether it was tuned on the reduced config
-        cfg = (registry.get_smoke(arch) if plan.mesh_kind == "local"
-               else registry.get(arch))
+        cfg = registry.for_plan(plan)
         shape = plan.shape_config()
     else:
         cfg = registry.get(arch)

@@ -9,9 +9,10 @@ knobs (leave ``--c`` unset for the cost-model pick; ``--kernel`` defaults
 to the backend: Pallas on TPU, the jnp reference on CPU). ``--save-plan``
 persists the resolved plan for replay / CI artifacts.
 
-CPU-runnable reduced mode (the default serves a mixed workload of
-``--requests`` requests with staggered prompt lengths / budgets through the
-engine and prints per-request generations + engine metrics):
+CPU-runnable reduced mode (the default serves ``--requests`` requests with
+prompt lengths spread over ``[--prompt-len / 4, --prompt-len]`` and a
+``--gen`` token budget each through the engine, and prints per-request
+generations + engine metrics):
 
   PYTHONPATH=src python -m repro.launch.serve --arch h2o-danube-1.8b \
       --smoke --devices 8 --c 1 --requests 8 --prompt-len 16 --gen 8
@@ -140,13 +141,14 @@ def _engine_main(args, plan, cfg, registry=None, tracer=None):
     rng = np.random.default_rng(args.seed)
     vocab = engine.cfg.vocab_size
     reqs = []
+    lo = max(1, args.prompt_len // 4)
     for i in range(args.requests):
-        # staggered mixed workload: prompts and budgets vary per request
-        plen = max(1, args.prompt_len // 2 + (i * 3) % (args.prompt_len + 1))
-        gen = max(1, args.gen // 2 + i % (args.gen + 1))
+        # staggered workload: prompt lengths spread evenly over
+        # [prompt_len / 4, prompt_len], a --gen token budget each
+        plen = lo + (args.prompt_len - lo) * i // max(args.requests - 1, 1)
         reqs.append(Request(
             uid=f"req{i}", tokens=rng.integers(0, vocab, plen).tolist(),
-            max_new_tokens=gen, temperature=args.temperature,
+            max_new_tokens=args.gen, temperature=args.temperature,
             top_k=args.top_k, top_p=args.top_p, seed=args.seed + i))
     for r in reqs:
         engine.add_request(r)
@@ -285,10 +287,7 @@ def _resolve_plan(args):
             raise SystemExit(
                 f"[serve] plan {args.plan} names unknown arch "
                 f"{plan.arch!r}; known: {sorted(registry.ASSIGNED_ARCHS)}")
-        # mesh_kind='local' plans are smoke runs (same convention as
-        # launch.train); production plans carry the full config
-        return (registry.get_smoke(plan.arch) if plan.mesh_kind == "local"
-                else registry.get(plan.arch))
+        return registry.for_plan(plan)
 
     if args.plan:
         rec = json.loads(open(args.plan).read())
@@ -309,10 +308,9 @@ def _resolve_plan(args):
     import jax
 
     cfg = registry.get_smoke(args.arch) if args.smoke else registry.get(args.arch)
-    # --smoke = forced-host/local mesh; otherwise the production mesh
-    # (mesh_kind also encodes smoke-ness for --plan replay, as in
-    # launch.train). With --replicas/--roles the plan's n_devices is the
-    # per-replica share of the visible devices.
+    # the plan spans the devices this process sees (TPU chips, or forced
+    # host devices); with --replicas/--roles its n_devices is the
+    # per-replica share of them
     if args.roles:
         roles = [r.strip() for r in args.roles.split(",") if r.strip()]
         n_dev = len(jax.devices()) // len(roles)
@@ -320,7 +318,6 @@ def _resolve_plan(args):
             cfg, roles=roles, n_devices=n_dev, arch=args.arch,
             data=args.data, c=args.c, decode_batch=args.max_slots,
             page_size=args.page_size, max_len=args.max_len,
-            mesh_kind="local" if args.smoke else "production",
             kernel_impl=args.kernel, prefix_cache=bool(args.prefix_cache),
             host_tier_bytes=args.host_tier_bytes)
         return plans[0], plans, cfg
@@ -329,18 +326,20 @@ def _resolve_plan(args):
     plan = make_serve_plan(
         cfg, arch=args.arch, n_devices=n_dev, data=args.data,
         c=args.c, decode_batch=args.max_slots, page_size=args.page_size,
-        max_len=args.max_len, mesh_kind="local" if args.smoke
-        else "production", kernel_impl=args.kernel,
-        replicas=replicas, prefix_cache=bool(args.prefix_cache),
-        host_tier_bytes=args.host_tier_bytes)
+        max_len=args.max_len, kernel_impl=args.kernel, replicas=replicas,
+        prefix_cache=bool(args.prefix_cache), host_tier_bytes=args.host_tier_bytes)
     return plan, None, cfg
 
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture id (optional with --plan)")
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the registry's reduced config (CPU-runnable)")
     ap.add_argument("--devices", type=int, default=0)
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--c", type=int, default=None,
@@ -454,10 +453,22 @@ def main(argv=None):
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
+    if args.http and args.workers > 0:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            # this process now holds the chips, and a spawned worker could
+            # not get one: serve them from in-process replicas instead
+            raise SystemExit(
+                "[serve] --workers spawns engine processes, but a TPU chip "
+                "belongs to one process and this one holds them; serve "
+                "in-process replicas instead: --workers 0 for one, or the "
+                "gateway's --replicas N")
 
     plan, plans, cfg = _resolve_plan(args)
     print(f"[serve] plan: P_sp={plan.sp_size} scheme={plan.scheme} "
           f"C={plan.c} R={plan.r} data={plan.data} "
+          f"block_impl={plan.block_impl} "
           f"kernel={plan.kernel_impl} slots={plan.decode_batch} "
           f"page={plan.page_size} capacity={plan.seq_len} "
           f"replicas={len(plans) if plans else plan.replicas} "

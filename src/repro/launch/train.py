@@ -6,27 +6,44 @@ on the spot (``--autotune``: measure the analytical top-k arrangements,
 persist + use the winner), or resolved from the CLI knobs by the analytical
 cost model (leave ``--c``/``--scheme`` unset to let the model pick).
 
-On real hardware this runs the production mesh; on CPU use --devices to
-force host devices and a reduced config for a real multi-step run:
+The plan spans every device this process sees: the chips of a TPU host
+(published widths unless --smoke), or on CPU the host devices --devices
+forces, typically with the reduced config:
 
   PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \
       --smoke --devices 8 --data 2 --c 1 --steps 20
+
+  python -m repro.launch.train --arch h2o-danube-1.8b --seq-len 8192 \
+      --batch 1 --steps 3 --scheme startrail --c 2   # four v5e chips
 """
 
 import argparse
 import os
 
 
+def device_plan(cfg, shape, *, arch, **knobs):
+    """The training plan over every device this process sees; knobs left
+    unset are the cost model's and the backend's picks (``make_plan``)."""
+    import jax
+
+    from repro.plan import make_plan
+
+    return make_plan(cfg, shape, arch=arch, n_devices=jax.device_count(),
+                     **knobs)
+
+
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture id (optional with --plan)")
-    ap.add_argument("--shape", default="train_4k")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config + tiny shape (CPU-runnable)")
+                    help="the registry's reduced config (CPU-runnable)")
     ap.add_argument("--devices", type=int, default=0,
                     help="force N host devices (CPU)")
-    ap.add_argument("--data", type=int, default=2)
+    ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--c", type=int, default=None,
                     help="StarTrail C (default: cost-model pick)")
     ap.add_argument("--scheme", default=None,
@@ -52,7 +69,6 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--metrics", default=None)
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--rules", default="default")
     # observability (repro.obs; all off by default = near-zero overhead)
     ap.add_argument("--metrics-dump", default=None,
@@ -80,49 +96,44 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
+    import jax
+
     from repro.configs import registry
-    from repro.configs.base import SHAPES, ShapeConfig
+    from repro.configs.base import ShapeConfig
     from repro.models.factory import build_model
     from repro.optim import adamw
-    from repro.plan import ExecutionPlan, autotune as autotune_lib, make_plan
+    from repro.plan import ExecutionPlan, autotune as autotune_lib
     from repro.train import trainer as trainer_lib
 
     if args.plan:
         plan = ExecutionPlan.load(args.plan)
-        cfg = (registry.get_smoke(plan.arch) if plan.mesh_kind == "local"
-               else registry.get(plan.arch))
+        cfg = registry.for_plan(plan)
         print(f"[train] loaded plan {args.plan}: scheme={plan.scheme} "
               f"C={plan.c} R={plan.r} microbatches={plan.microbatches}")
     else:
-        if args.smoke:
-            cfg = registry.get_smoke(args.arch)
-            shape = ShapeConfig("smoke", seq_len=args.seq_len,
-                                global_batch=args.batch, kind="train")
-            n_devices, data, pod, mesh_kind = (args.devices, args.data, 1,
-                                               "local")
-        else:
-            cfg = registry.get(args.arch)
-            shape = SHAPES[args.shape]
-            pod = 2 if args.multi_pod else 1
-            n_devices, data, mesh_kind = 256 * pod, 16, "production"
+        cfg = (registry.get_smoke(args.arch) if args.smoke
+               else registry.get(args.arch))
+        shape = ShapeConfig("smoke" if args.smoke else f"seq{args.seq_len}",
+                            seq_len=args.seq_len, global_batch=args.batch,
+                            kind="train")
         if args.autotune:
             tuned = autotune_lib.autotune(
-                cfg, shape, arch=args.arch, n_devices=n_devices, data=data,
-                mesh_kind=mesh_kind, microbatches=args.microbatches)
+                cfg, shape, arch=args.arch, n_devices=jax.device_count(),
+                data=args.data, microbatches=args.microbatches)
             plan = tuned["plan"]
             print(f"[train] autotuned plan -> {tuned['path']}: "
                   f"scheme={plan.scheme} C={plan.c} R={plan.r}")
         else:
-            plan = make_plan(
-                cfg, shape, arch=args.arch, n_devices=n_devices, data=data,
-                pod=pod, scheme=args.scheme, c=args.c,
+            plan = device_plan(
+                cfg, shape, arch=args.arch,
+                data=args.data, scheme=args.scheme, c=args.c,
                 placement=args.placement, microbatches=args.microbatches,
-                mesh_kind=mesh_kind, sharding_rules=args.rules,
+                sharding_rules=args.rules,
                 pipeline_scan=not args.no_pipeline,
                 comm_chunks=args.comm_chunks)
     print(f"[train] plan: P_sp={plan.sp_size} scheme={plan.scheme} "
           f"C={plan.c} R={plan.r} data={plan.data} "
-          f"microbatches={plan.microbatches}")
+          f"microbatches={plan.microbatches} block_impl={plan.block_impl}")
 
     model = build_model(cfg)
     adam_cfg = adamw.AdamWConfig(learning_rate=args.lr, warmup_steps=5,

@@ -24,6 +24,13 @@ class Model:
     def init(self, key: jax.Array):
         return spec.init_tree(self.specs, key, self.cfg.param_dtype)
 
+    def init_sharded(self, shardings, seed: int = 0):
+        """``init(PRNGKey(seed))`` created where it lives: one jit whose
+        outputs carry ``shardings`` (a tree of ``Sharding`` matching the
+        params), so no device ever holds the whole unsharded tree."""
+        return jax.jit(self.init, out_shardings=shardings)(
+            jax.random.PRNGKey(seed))
+
     def abstract(self):
         return spec.abstract_tree(self.specs, self.cfg.param_dtype)
 

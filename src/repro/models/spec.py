@@ -53,7 +53,12 @@ def init_tree(tree, key: jax.Array, default_dtype: str):
             return jnp.ones(spec.shape, dtype)
         fan_in = spec.shape[0] if spec.shape else 1
         scale = spec.scale if spec.scale is not None else fan_in ** -0.5
-        return (jax.random.normal(keys[i], spec.shape, jnp.float32) * scale).astype(dtype)
+        z = jax.random.normal(keys[i], spec.shape, jnp.float32)
+        # under jit XLA would fuse the scale into the sampler and round
+        # differently; the barrier keeps jitted (sharded) init bit-equal to
+        # eager init of the same seed
+        z = jax.lax.optimization_barrier(z)
+        return (z * scale).astype(dtype)
 
     return tree_map_specs(init_one, tree)
 

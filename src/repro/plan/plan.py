@@ -23,6 +23,7 @@ import json
 import pathlib
 from typing import Dict, List, Optional, Sequence
 
+from repro.configs import registry
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.dist.meshes import PLACEMENTS
 from repro.plan import cost
@@ -52,7 +53,10 @@ class ExecutionPlan:
     microbatches: int = 1
     sharding_rules: str = "default"
     grad_compression: str = "none"
-    mesh_kind: str = "local"       # 'local' (forced-host) | 'production'
+    # 'local': the devices this process sees (TPU chips, or forced CPU host
+    # devices); 'production': the 16x16 / 2x16x16 grid of launch.dryrun
+    mesh_kind: str = "local"
+    smoke: bool = False            # built on the registry's reduced config
     unroll_scans: bool = False
     # double-buffered ring scans (issue the next transfer before the block
     # kernel) + ring-transfer sub-chunking; see core/startrail.py
@@ -310,8 +314,9 @@ def make_plan(cfg: ModelConfig, shape: ShapeConfig, *, arch: Optional[str]
             cfg, shape, sp, picked, batch=max(shape.global_batch // dp, 1),
             cluster=cluster, overlap_frac=overlap_frac)
 
+    arch = arch or cfg.name
     return ExecutionPlan(
-        arch=arch or cfg.name, shape=shape.name, seq_len=shape.seq_len,
+        arch=arch, shape=shape.name, seq_len=shape.seq_len,
         global_batch=shape.global_batch, n_devices=n_devices,
         kind=shape.kind, data=data, pod=pod, scheme=picked.scheme,
         c=picked.c,
@@ -321,8 +326,9 @@ def make_plan(cfg: ModelConfig, shape: ShapeConfig, *, arch: Optional[str]
         block_skip=cfg.window is not None and seq_scheme == "contiguous",
         remat=remat, microbatches=microbatches,
         sharding_rules=sharding_rules, grad_compression=grad_compression,
-        mesh_kind=mesh_kind, unroll_scans=unroll_scans,
-        pipeline_scan=pipeline_scan, comm_chunks=comm_chunks)
+        mesh_kind=mesh_kind, smoke=registry.is_smoke(arch, cfg),
+        unroll_scans=unroll_scans, pipeline_scan=pipeline_scan,
+        comm_chunks=comm_chunks)
 
 
 def make_serve_plan(cfg: ModelConfig, *, arch: Optional[str] = None,

@@ -22,6 +22,7 @@ measuring real step durations rather than dispatch time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import pathlib
 import time
@@ -73,9 +74,13 @@ def train(model: Model, plan: ExecutionPlan, adam_cfg: adamw.AdamWConfig,
                                   sp_size=sp_size)
 
     start = 0
+    # state is created in its sharded layout (no full copy on one device)
     if params is None:
-        params = model.init(jax.random.PRNGKey(tcfg.seed))
-    opt = adamw.init_state(params, adam_cfg)
+        params = model.init_sharded(sh["params"], tcfg.seed)
+    else:
+        params = jax.device_put(params, sh["params"])
+    opt = jax.jit(functools.partial(adamw.init_state, cfg=adam_cfg),
+                  out_shardings=sh["opt"])(params)
     if tcfg.ckpt_dir:
         # common step across params + opt trees: a crash between the two
         # writes leaves them one step apart, and only a step present in
@@ -89,9 +94,6 @@ def train(model: Model, plan: ExecutionPlan, adam_cfg: adamw.AdamWConfig,
                 pathlib.Path(tcfg.ckpt_dir) / "opt", last, opt, sh["opt"])
             start = last
             print(f"[trainer] restored step {last}")
-
-    params = jax.device_put(params, sh["params"])
-    opt = jax.device_put(opt, sh["opt"])
 
     prefetch = Prefetcher(data_source, start_step=start)
     detector = elastic.StragglerDetector()

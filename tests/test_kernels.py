@@ -196,9 +196,8 @@ def test_resolve_impl():
 
 def test_pallas_batched_positions_no_fallback():
     """impl='pallas' with batched (B, S) positions (per-sequence cache
-    lengths) runs the scalar-prefetch ragged kernels — forward AND
-    backward. The fallback counter stays empty and both directions match
-    the reference."""
+    lengths) runs the flash kernels — forward AND backward. The fallback
+    counter stays empty and both directions match the reference."""
     key = jax.random.PRNGKey(3)
     q, k, v, do = _data(key, 2, 8, 8, 2, 2, 16, jnp.float32)
     pos_shared = jnp.arange(8, dtype=jnp.int32)
@@ -251,9 +250,9 @@ RAGGED_BWD_CASES = [
 
 @pytest.mark.parametrize("B,Sq,Sk,Hq,Hkv,D,causal,window", RAGGED_BWD_CASES)
 def test_bwd_ragged_matches_ref(B, Sq, Sk, Hq, Hkv, D, causal, window):
-    """The scalar-prefetch ragged backward kernels (per-batch positions
-    sliced from SMEM) vs the reference backward, including per-row offsets
-    that differ across the batch."""
+    """The backward kernels with per-batch (B, S) positions vs the
+    reference backward, including per-row offsets that differ across the
+    batch."""
     q, k, v, do = _data(jax.random.PRNGKey(7), B, Sq, Sk, Hq, Hkv, D,
                         jnp.float32)
     base_q = jnp.arange(Sq, dtype=jnp.int32)
@@ -341,9 +340,9 @@ def test_no_direct_kernel_imports():
     src = pathlib.Path(__file__).resolve().parent.parent / "src" / "repro"
     pat = re.compile(
         r"repro\.kernels\s+import\s+"
-        r"(ref|ops|flash_attention|paged_decode|ragged_prefill|paged_prefill)"
+        r"(ref|ops|flash_attention|paged_decode|paged_prefill)"
         r"|repro\.kernels\."
-        r"(ref|ops|flash_attention|paged_decode|ragged_prefill|paged_prefill)")
+        r"(ref|ops|flash_attention|paged_decode|paged_prefill)")
     offenders = []
     for path in sorted(src.rglob("*.py")):
         rel = path.relative_to(src)

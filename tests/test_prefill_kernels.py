@@ -1,8 +1,8 @@
-"""Interpret-mode parity suite for the two serving prefill kernels:
+"""Interpret-mode parity suite for the serving prefill kernels:
 
-  * ``kernels/ragged_prefill.py`` — batched per-row positions via scalar
-    prefetch (retires the block_fwd batched-positions fallback), checked
-    against ``kernels.ref.block_attention``;
+  * ``kernels/flash_attention.py`` with batched per-row ``(B, S)``
+    positions (the engine's ragged buckets), checked against
+    ``kernels.ref.block_attention``;
   * ``kernels/paged_prefill.py`` — suffix queries vs the page-table-indexed
     cached prefix, checked against the dense-gather reference path of
     ``kernels.dispatch.paged_prefill`` and, combined across shards, against
@@ -28,7 +28,7 @@ from repro.core import combine
 from repro.core.combine import NEG_INF
 from repro.kernels import dispatch, ref
 from repro.kernels.paged_prefill import paged_prefill_attention
-from repro.kernels.ragged_prefill import choose_block, ragged_prefill_fwd
+from repro.kernels.flash_attention import choose_block, flash_attention_fwd
 
 
 def _qkv(key, B, Sq, Sk, Hq, Hkv, D, dtype=jnp.float32):
@@ -93,7 +93,7 @@ def test_ragged_prefill_matches_ref(name, B, Sq, Sk, Hq, Hkv, D, window,
     else:
         lens_arr = list(lens) + [Sk] * (B - len(lens))
         pos_q, pos_k = _ragged_positions(B, Sq, Sk, lens_arr)
-    o_pl, lse_pl = ragged_prefill_fwd(
+    o_pl, lse_pl = flash_attention_fwd(
         q, k, v, pos_q, pos_k, causal=True, window=window, interpret=True)
     o_ref, lse_ref = ref.block_attention(
         q, k, v, pos_q, pos_k, causal=True, window=window)
@@ -125,7 +125,7 @@ def test_ragged_prefill_len_zero_rows_are_dead():
     B, Sq, Sk, Hq, Hkv, D = 2, 8, 8, 2, 2, 16
     q, k, v = _qkv(jax.random.PRNGKey(0), B, Sq, Sk, Hq, Hkv, D)
     pos_q, pos_k = _ragged_positions(B, Sq, Sk, [0, 8])
-    o, lse = ragged_prefill_fwd(q, k, v, pos_q, pos_k, causal=True,
+    o, lse = flash_attention_fwd(q, k, v, pos_q, pos_k, causal=True,
                                 interpret=True)
     np.testing.assert_array_equal(np.asarray(o)[0], 0.0)
     np.testing.assert_array_equal(np.asarray(lse)[0], np.float32(NEG_INF))
@@ -137,9 +137,9 @@ def test_ragged_prefill_shared_positions_broadcast():
     B, S, H, D = 2, 16, 2, 32
     q, k, v = _qkv(jax.random.PRNGKey(1), B, S, S, H, H, D)
     pos = jnp.arange(S, dtype=jnp.int32)
-    o1, lse1 = ragged_prefill_fwd(q, k, v, pos, pos, causal=True,
+    o1, lse1 = flash_attention_fwd(q, k, v, pos, pos, causal=True,
                                   interpret=True)
-    o2, lse2 = ragged_prefill_fwd(
+    o2, lse2 = flash_attention_fwd(
         q, k, v, jnp.broadcast_to(pos[None], (B, S)),
         jnp.broadcast_to(pos[None], (B, S)), causal=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
@@ -151,7 +151,7 @@ def test_ragged_prefill_prefix_lm():
     B, S, H, D = 2, 16, 2, 16
     q, k, v = _qkv(jax.random.PRNGKey(2), B, S, S, H, H, D)
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    o_pl, lse_pl = ragged_prefill_fwd(q, k, v, pos, pos, causal=True,
+    o_pl, lse_pl = flash_attention_fwd(q, k, v, pos, pos, causal=True,
                                       prefix_len=6, interpret=True)
     o_ref, lse_ref = ref.block_attention(q, k, v, pos, pos, causal=True,
                                          prefix_len=6)
@@ -161,7 +161,7 @@ def test_ragged_prefill_prefix_lm():
 
 def test_dispatch_batched_fwd_routes_to_ragged_kernel():
     """dispatch.block_fwd(impl='pallas') with (B, S) positions returns the
-    ragged kernel's result (not the ref fallback) and counts nothing."""
+    flash kernel's result (not the ref fallback) and counts nothing."""
     B, S, H, D = 2, 8, 2, 16
     q, k, v = _qkv(jax.random.PRNGKey(3), B, S, S, H, H, D)
     pos_q, pos_k = _ragged_positions(B, S, S, [8, 3])
@@ -169,7 +169,7 @@ def test_dispatch_batched_fwd_routes_to_ragged_kernel():
     o_d, lse_d = dispatch.block_fwd(q, k, v, pos_q, pos_k, causal=True,
                                     impl="pallas")
     assert dispatch.pallas_fallbacks() == {}
-    o_k, lse_k = ragged_prefill_fwd(q, k, v, pos_q, pos_k, causal=True)
+    o_k, lse_k = flash_attention_fwd(q, k, v, pos_q, pos_k, causal=True)
     np.testing.assert_array_equal(np.asarray(o_d), np.asarray(o_k))
     np.testing.assert_array_equal(np.asarray(lse_d), np.asarray(lse_k))
 

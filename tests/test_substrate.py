@@ -240,3 +240,23 @@ def test_scheduler_profile_fn_hook():
     cl = sch.ClusterModel(sp_size=16)
     out = sch.schedule(w, cl, profile_fn=lambda c, p: abs(c - 2) + (p == "ring_inner") * 0.1)
     assert out["best"]["c"] == 2 and out["best"]["placement"] == "team_inner"
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "jamba-1.5-large-398b"])
+def test_init_sharded_matches_eager_init(arch):
+    """Params created in their sharded layout (one jit with out_shardings,
+    as the trainer and engine do) equal eager ``model.init`` of the same
+    seed bit for bit."""
+    from jax.sharding import NamedSharding
+
+    from repro.configs import registry
+    from repro.dist import meshes
+    from repro.models.factory import build_model
+
+    model = build_model(registry.get_smoke(arch))
+    mesh = meshes.local_mesh_for_tests(c=1, r=1)
+    sh = jax.tree.map(lambda s: NamedSharding(mesh, s), model.partition())
+    want = model.init(jax.random.PRNGKey(5))
+    got = model.init_sharded(sh, seed=5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -40,7 +40,7 @@ BATCHES = {
     ],
     "plan_and_microbatch": [
         "microbatch_equiv", "scheme_crosscheck", "ulysses_rejected",
-        "plan_constructs", "commlog_c2",
+        "plan_constructs", "commlog_c2", "init_sharded",
     ],
     "pipelined_scan": [
         "pipelined_bitexact", "bwd_skip_equiv",

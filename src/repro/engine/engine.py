@@ -49,6 +49,14 @@ from repro.models.factory import Model
 
 _ENGINE_IDS = itertools.count()
 
+#: host phases of a driver step, timed on every step (``perf_counter``)
+#: and, when tracing, each an ``engine/<phase>`` span: admission, a
+#: prefill's input building and launch, its blocking first-token read, the
+#: decode step's inputs, its launch (until the jitted call returns) and its
+#: blocking token read, and the emitted tokens' bookkeeping
+PHASES = ("admit", "prefill_launch", "prefill_wait", "decode_inputs",
+          "decode_launch", "decode_wait", "emit")
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -122,6 +130,7 @@ class EngineMetrics:
         "preemptions": ("engine_preemptions_total", "counter", int),
     }
     _HISTOGRAMS = ("serve_ttft_seconds", "serve_intertoken_seconds")
+    _PHASES = "engine_phase_seconds_total"
 
     def __init__(self, registry: Optional[obs.Registry] = None,
                  labels: Optional[Dict[str, str]] = None, **initial):
@@ -139,6 +148,7 @@ class EngineMetrics:
         reg.histogram("serve_intertoken_seconds",
                       "Gap between consecutive emitted tokens of a request",
                       buckets=obs.INTERTOKEN_BUCKETS)
+        reg.counter(self._PHASES, "Host seconds in each phase of a step")
         for name, v in initial.items():
             setattr(self, name, v)
 
@@ -161,7 +171,7 @@ class EngineMetrics:
                       self.transfer_compiles)
         for name in self._SPECS:
             setattr(self, name, 0)
-        for name in self._HISTOGRAMS:
+        for name in self._HISTOGRAMS + (self._PHASES,):
             self.registry.get(name).reset(**self.labels)
         if keep_compiles:
             self.prefill_compiles, self.decode_compiles = pc, dc
@@ -179,6 +189,15 @@ class EngineMetrics:
         d["prefix_hit_rate"] = (self.prefill_tokens_cached / prompt
                                 if prompt else 0.0)
         return d
+
+    def add_phases(self, seconds: Dict[str, float]) -> None:
+        c = self.registry.get(self._PHASES)
+        for phase, s in seconds.items():
+            c.inc(s, phase=phase, **self.labels)
+
+    def phase_seconds(self) -> Dict[str, float]:
+        c = self.registry.get(self._PHASES)
+        return {p: c.value(phase=p, **self.labels) for p in PHASES}
 
     # latency histograms (driver-facing)
     def observe_ttft(self, seconds: float) -> None:
@@ -285,11 +304,13 @@ class Engine:
                     "tokens to the rest of the prompt)")
         self._prefilling: List[SlotState] = []
         self.last_step_prefills: List[Tuple[int, int]] = []
+        self.last_step_phases: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         # every step runs under this obs scope, so trace-time events in the
         # process-global registry (dispatch's pallas->ref fallbacks) carry
         # a scope label attributing them to this engine instance
         self.obs_scope = f"engine{next(_ENGINE_IDS)}"
         self.tracer = tracer if tracer is not None else obs.NULL_TRACER
+        self.host = obs.watch_host(self.tracer)
         self._arrival: Dict[str, float] = {}     # uid -> enqueue time
         self._last_emit: Dict[str, float] = {}   # uid -> last token time
         self._req_spans: Dict[str, Optional[str]] = {}
@@ -528,6 +549,7 @@ class Engine:
         self._prefilling = []
         self._handoff_ready = []
         self.last_step_prefills = []
+        self.last_step_phases = dict.fromkeys(PHASES, 0.0)
         self._arrival.clear()
         self._last_emit.clear()
         self._req_spans.clear()
@@ -562,7 +584,7 @@ class Engine:
         fn = self._prefill_fns.get((bucket_len, sampled))
         if fn is not None:
             return fn
-        cfg, eng, sc = self.cfg, self.eng, self._sc
+        cfg, eng = self.cfg, self.eng
         rt = dc.replace(self.rt, st_cfg=dc.replace(self.rt.st_cfg,
                                                    seq_len=bucket_len))
         pat = transformer.layer_pattern(cfg)
@@ -573,20 +595,15 @@ class Engine:
                 rt, params, {"tokens": tokens}, cfg,
                 prompt_len=prompt_len, return_hidden=True)
             subs = {}
-            for i in range(len(pat)):
-                subs[f"sub{i}"] = paged_cache.insert_prompt(
-                    rt, pools["stack"][f"sub{i}"],
-                    cache["stack"][f"sub{i}"]["k"],
-                    cache["stack"][f"sub{i}"]["v"],
-                    table_row, prompt_len[0], eng.page_size)
-            head = params.get("lm_head", params["embed"])
-            if sampled:
-                k1 = jax.random.fold_in(key, prompt_len[0])
-                tok = sampling_lib.sample(
-                    rt, head, last, cfg, temperature=temp, top_k=top_k,
-                    top_p=top_p, keys=k1[None], sc=sc)
-            else:
-                tok = sampling_lib.greedy(rt, head, last, cfg)
+            with jax.named_scope("prefill/attn/kv_write"):
+                for i in range(len(pat)):
+                    subs[f"sub{i}"] = paged_cache.insert_prompt(
+                        rt, pools["stack"][f"sub{i}"],
+                        cache["stack"][f"sub{i}"]["k"],
+                        cache["stack"][f"sub{i}"]["v"],
+                        table_row, prompt_len[0], eng.page_size)
+            tok = self._first_token(rt, params, last, prompt_len, sampled,
+                                    temp, top_k, top_p, key)
             return tok, {"stack": subs}
 
         fn = jax.jit(jax.shard_map(
@@ -612,7 +629,7 @@ class Engine:
         fn = self._suffix_fns.get((bucket_len, sampled))
         if fn is not None:
             return fn
-        cfg, eng, sc = self.cfg, self.eng, self._sc
+        cfg, eng = self.cfg, self.eng
         rt = dc.replace(self.rt, st_cfg=dc.replace(self.rt.st_cfg,
                                                    seq_len=bucket_len))
 
@@ -622,14 +639,8 @@ class Engine:
                 rt, params, {"tokens": tokens}, cfg,
                 prompt_len=prompt_len, cached_len=cached_len, pools=pools,
                 table_row=table_row, page_size=eng.page_size)
-            head = params.get("lm_head", params["embed"])
-            if sampled:
-                k1 = jax.random.fold_in(key, prompt_len[0])
-                tok = sampling_lib.sample(
-                    rt, head, last, cfg, temperature=temp, top_k=top_k,
-                    top_p=top_p, keys=k1[None], sc=sc)
-            else:
-                tok = sampling_lib.greedy(rt, head, last, cfg)
+            tok = self._first_token(rt, params, last, prompt_len, sampled,
+                                    temp, top_k, top_p, key)
             return tok, new_pools
 
         fn = jax.jit(jax.shard_map(
@@ -641,6 +652,22 @@ class Engine:
         self._suffix_fns[(bucket_len, sampled)] = fn
         self.metrics.prefill_compiles += 1
         return fn
+
+    def _first_token(self, rt, params, last, prompt_len, sampled: bool,
+                     temp, top_k, top_p, key):
+        """A prefill island's first token from the last prompt position's
+        hidden state: greedy, or sampled with the fold the decode step
+        continues from (request seed, position ``prompt_len``)."""
+        import jax
+
+        head = params.get("lm_head", params["embed"])
+        with jax.named_scope("prefill/head"):
+            if not sampled:
+                return sampling_lib.greedy(rt, head, last, self.cfg)
+            k1 = jax.random.fold_in(key, prompt_len[0])
+            return sampling_lib.sample(
+                rt, head, last, self.cfg, temperature=temp, top_k=top_k,
+                top_p=top_p, keys=k1[None], sc=self._sc)
 
     def _decode_fn(self, width: int, sampled: bool):
         """One jit per (table-width bucket, any-active-request-samples)."""
@@ -712,6 +739,7 @@ class Engine:
         ``prompt_len``) is the same as the monolithic path's, so chunking
         never changes the emitted stream.
         """
+        t0 = time.perf_counter()
         req = st.req
         m = self.metrics
         if st.pending_reload:
@@ -755,9 +783,23 @@ class Engine:
         m.prefill_tokens_computed += end - start
         m.prefill_chunks += 1
         self.last_step_prefills.append((start, end))
-        return int(np.asarray(tok)[0, 0]) if final else None
+        phases = self.last_step_phases
+        t1 = time.perf_counter()
+        phases["prefill_launch"] += t1 - t0
+        if not final:
+            return None
+        with self.tracer.span("engine/prefill_wait", cat="engine"):
+            first = int(np.asarray(tok)[0, 0])
+        phases["prefill_wait"] += time.perf_counter() - t1
+        return first
 
     def _complete_prefill(self, st: SlotState, tok: int, emitted) -> None:
+        t0 = time.perf_counter()
+        with self.tracer.span("engine/emit", cat="engine"):
+            self._emit_first(st, tok, emitted)
+        self.last_step_phases["emit"] += time.perf_counter() - t0
+
+    def _emit_first(self, st: SlotState, tok: int, emitted) -> None:
         m = self.metrics
         self._prefilling.remove(st)
         self.scheduler.register_prefix(st)
@@ -819,14 +861,21 @@ class Engine:
                 self.tracer.span("engine/step", cat="engine",
                                  scope=self.obs_scope,
                                  step=self.metrics.steps):
-            return self._step_inner()
+            emitted = self._step_inner()
+        # always-on phase sums and the process's host counters reach the
+        # registry once per step, never per token
+        self.metrics.add_phases(self.last_step_phases)
+        self.host.fold()
+        return emitted
 
     def _step_inner(self) -> List[Tuple[str, int]]:
         t0 = time.monotonic()
+        clock = time.perf_counter
         emitted: List[Tuple[str, int]] = []
         m = self.metrics
         tracer = self.tracer
         self.last_step_prefills = []
+        phases = self.last_step_phases = dict.fromkeys(PHASES, 0.0)
 
         # commit spills staged by the previous step's evictions: host-tier
         # entries become hittable only once their d2h copy has landed (a
@@ -864,7 +913,10 @@ class Engine:
             # before the next is matched, so same-step bursts sharing a
             # prefix hit the cache (a *chunked* long prompt registers only
             # when its last chunk lands, steps later)
-            batch = self.scheduler.admit(m.steps, limit=1)
+            t = clock()
+            with tracer.span("engine/admit", cat="engine"):
+                batch = self.scheduler.admit(m.steps, limit=1)
+            phases["admit"] += clock() - t
             if not batch:
                 break
             st = batch[0]
@@ -888,46 +940,58 @@ class Engine:
             sampled = any(st.req.temperature > 0.0 for st in active)
             with tracer.span("engine/decode", cat="engine",
                              width=width, active=len(active)):
-                fn = self._decode_fn(width, sampled)
-                B = self.eng.max_slots
-                tokens = np.zeros((B, 1), np.int32)
-                cache_len = np.zeros((B,), np.int32)
-                temp = np.zeros((B,), np.float32)
-                top_k = np.zeros((B,), np.int32)
-                top_p = np.ones((B,), np.float32)
-                keys = np.zeros((B, 2), np.uint32)
-                act = np.zeros((B,), bool)
+                t_inputs = clock()
+                with tracer.span("engine/decode_inputs", cat="engine"):
+                    fn = self._decode_fn(width, sampled)
+                    B = self.eng.max_slots
+                    tokens = np.zeros((B, 1), np.int32)
+                    cache_len = np.zeros((B,), np.int32)
+                    temp = np.zeros((B,), np.float32)
+                    top_k = np.zeros((B,), np.int32)
+                    top_p = np.ones((B,), np.float32)
+                    keys = np.zeros((B, 2), np.uint32)
+                    act = np.zeros((B,), bool)
+                    for st in active:
+                        i = st.slot
+                        tokens[i, 0] = st.out[-1]
+                        cache_len[i] = st.cache_len
+                        temp[i] = st.req.temperature
+                        top_k[i] = st.req.top_k
+                        top_p[i] = st.req.top_p
+                        keys[i] = self._base_key(st.req.seed)
+                        act[i] = True
+                    table = np.ascontiguousarray(
+                        self.scheduler.table[:, :, :width])
+                t_launch = clock()
+                with tracer.span("engine/decode_launch", cat="engine"):
+                    tok, self.pools = fn(self.params, self.pools, tokens,
+                                         cache_len, table, temp, top_k,
+                                         top_p, keys, act)
+                t_wait = clock()
+                with tracer.span("engine/decode_wait", cat="engine"):
+                    tok = np.asarray(tok)
+                t_emit = clock()
+            with tracer.span("engine/emit", cat="engine"):
+                now = time.monotonic()
                 for st in active:
-                    i = st.slot
-                    tokens[i, 0] = st.out[-1]
-                    cache_len[i] = st.cache_len
-                    temp[i] = st.req.temperature
-                    top_k[i] = st.req.top_k
-                    top_p[i] = st.req.top_p
-                    keys[i] = self._base_key(st.req.seed)
-                    act[i] = True
-                table = np.ascontiguousarray(
-                    self.scheduler.table[:, :, :width])
-                tok, self.pools = fn(self.params, self.pools, tokens,
-                                     cache_len, table, temp, top_k, top_p,
-                                     keys, act)
-                tok = np.asarray(tok)
-            now = time.monotonic()
-            for st in active:
-                t = int(tok[st.slot, 0])
-                st.out.append(t)
-                st.cache_len += 1
-                emitted.append((st.req.uid, t))
-                m.tokens_out += 1
-                uid = st.req.uid
-                last = self._last_emit.get(uid)
-                if last is not None:
-                    m.observe_intertoken(now - last)
-                self._last_emit[uid] = now
-                if st.done:
-                    self._finish_request(st)
-            m.decode_steps += 1
-            m.occupancy_sum += len(active) / self.eng.max_slots
+                    t = int(tok[st.slot, 0])
+                    st.out.append(t)
+                    st.cache_len += 1
+                    emitted.append((st.req.uid, t))
+                    m.tokens_out += 1
+                    uid = st.req.uid
+                    last = self._last_emit.get(uid)
+                    if last is not None:
+                        m.observe_intertoken(now - last)
+                    self._last_emit[uid] = now
+                    if st.done:
+                        self._finish_request(st)
+                m.decode_steps += 1
+                m.occupancy_sum += len(active) / self.eng.max_slots
+            phases["decode_inputs"] += t_launch - t_inputs
+            phases["decode_launch"] += t_wait - t_launch
+            phases["decode_wait"] += t_emit - t_wait
+            phases["emit"] += clock() - t_emit
 
         m.peak_pages = max(m.peak_pages, self.scheduler.pages_in_use())
         m.steps += 1

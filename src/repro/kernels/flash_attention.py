@@ -311,6 +311,7 @@ def flash_attention_fwd(
         functools.partial(_fwd_kernel, causal=causal, window=window,
                           scale=scale, prefix_len=prefix_len, lay=lay,
                           merge=merge),
+        name="flash_attention_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hq, lay.n_q, lay.n_k),
@@ -456,6 +457,7 @@ def flash_attention_bwd(
                             lambda b, h, iq, ik, lv: (b, h, iq, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
+        name="flash_attention_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hq, n_q, lay.n_k),
@@ -481,6 +483,7 @@ def flash_attention_bwd(
         lambda b, h, ik, t, lv: (b, h * G + t // n_q, t % n_q, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
+        name="flash_attention_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, lay.n_k, G * n_q),

@@ -177,6 +177,7 @@ def paged_decode_attention(
 
     o, lse = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),

@@ -202,6 +202,7 @@ def paged_prefill_attention(
 
     o, lse = pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),

@@ -10,9 +10,11 @@ from repro.obs.registry import (  # noqa: F401
     parse_prometheus_families, scope,
 )
 from repro.obs.tracing import NULL_TRACER, Tracer  # noqa: F401
+from repro.obs.host import HostWatch, watch_host  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Tracer", "NULL_TRACER",
+    "HostWatch", "watch_host",
     "TTFT_BUCKETS", "INTERTOKEN_BUCKETS", "current_scope", "scope",
     "global_registry", "parse_prometheus", "parse_prometheus_families",
     "merge_prometheus_text",

@@ -107,15 +107,6 @@ class Tracer:
                     "tid": threading.get_ident(),
                     **({"args": args} if args else {})})
 
-    def instant(self, name: str, cat: str = "repro", **args) -> None:
-        """Zero-duration marker (ph='i')."""
-        if not self.enabled:
-            return
-        self._emit({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": self._now_us(), "pid": os.getpid(),
-                    "tid": threading.get_ident(),
-                    **({"args": args} if args else {})})
-
     # -- buffer -----------------------------------------------------------
     def _emit(self, event: Dict[str, Any]) -> None:
         with self._lock:

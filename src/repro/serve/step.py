@@ -54,19 +54,20 @@ def _attn_decode(rt: Runtime, p, x, cache, cfg: ModelConfig, cache_len,
       between requests).
     """
     B = x.shape[0]
-    h = blocks.rmsnorm(p["norm"], x, cfg.norm_eps)
-    wq = rt.dense(p["wq"], ("embed", "heads", "head_dim"))
-    wk = rt.dense(p["wk"], ("embed", "kv_heads", "head_dim"))
-    wv = rt.dense(p["wv"], ("embed", "kv_heads", "head_dim"))
-    wo = rt.dense(p["wo"], ("heads", "head_dim", "embed_out"))
-
-    cl = jnp.asarray(cache_len, jnp.int32)
-    if cl.ndim == 0:
-        cl = jnp.broadcast_to(cl, (B,))
-    pos_new = cl[:, None]                                       # (B, 1)
-    q = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wq), pos_new, cfg.rope_theta)
-    k_new = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wk), pos_new, cfg.rope_theta)
-    v_new = jnp.einsum("bsd,dhk->bshk", h, wv)
+    with jax.named_scope("qkv"):
+        h = blocks.rmsnorm(p["norm"], x, cfg.norm_eps)
+        wq = rt.dense(p["wq"], ("embed", "heads", "head_dim"))
+        wk = rt.dense(p["wk"], ("embed", "kv_heads", "head_dim"))
+        wv = rt.dense(p["wv"], ("embed", "kv_heads", "head_dim"))
+        cl = jnp.asarray(cache_len, jnp.int32)
+        if cl.ndim == 0:
+            cl = jnp.broadcast_to(cl, (B,))
+        pos_new = cl[:, None]                                   # (B, 1)
+        q = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wq), pos_new,
+                        cfg.rope_theta)
+        k_new = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wk), pos_new,
+                            cfg.rope_theta)
+        v_new = jnp.einsum("bsd,dhk->bshk", h, wv)
 
     if paged is not None:
         # paged pool: write the token, then hand the page table straight to
@@ -76,42 +77,48 @@ def _attn_decode(rt: Runtime, p, x, cache, cfg: ModelConfig, cache_len,
         # contiguous path does.
         from repro.engine import paged_cache as paged_lib
 
-        new_cache, tbl = paged_lib.write_token(
-            rt, cache, k_new, v_new, paged, cl, active)
-        o_p, lse_p = kernels.paged_decode(
-            q, new_cache["k"], new_cache["v"], tbl, cl, rt.sp_rank(),
-            sp=rt.sp_size(), page_size=paged.page_size, window=cfg.window,
-            impl=rt.kernel_impl)
-        o = st.combine_decode_partials(
-            o_p, lse_p, rt.sp_axes).astype(x.dtype)
-        out = jnp.einsum("bshk,hkd->bsd", o, wo)
-        return x + out, new_cache
+        with jax.named_scope("kv_write"):
+            new_cache, tbl = paged_lib.write_token(
+                rt, cache, k_new, v_new, paged, cl, active)
+        with jax.named_scope("paged"):
+            o_p, lse_p = kernels.paged_decode(
+                q, new_cache["k"], new_cache["v"], tbl, cl, rt.sp_rank(),
+                sp=rt.sp_size(), page_size=paged.page_size,
+                window=cfg.window, impl=rt.kernel_impl)
+            o = st.combine_decode_partials(
+                o_p, lse_p, rt.sp_axes).astype(x.dtype)
     else:
         s_loc = cache["k"].shape[1]
-        pos_k = rt.positions_contig(s_loc)
-        # append the new K/V into its owning shard's slot
-        local_slot = cl - (rt.sp_rank() if rt.mode == "spmd" else 0) * s_loc
-        write = jnp.arange(s_loc)[None] == local_slot[:, None]  # (B, S_loc)
-        if active is not None:
-            write &= active[:, None]
-        write = write[..., None, None]
-        k_cache = jnp.where(write, k_new.astype(cache["k"].dtype), cache["k"])
-        v_cache = jnp.where(write, v_new.astype(cache["v"].dtype), cache["v"])
-        new_cache = {"k": k_cache, "v": v_cache}
-        valid = pos_k[None] <= cl[:, None]                      # (B, S_loc)
-        # hide unfilled slots by pushing their positions beyond the query
-        pos_k = jnp.where(valid, pos_k[None], (cl + 1)[:, None])
-
-    cfg_st = dataclasses.replace(
-        rt.st_cfg, causal=True, window=cfg.window, prefix_len=None)
-    if rt.mode == "local":
-        o = kernels.prefill(q, k_cache, v_cache, pos_new, pos_k,
-                            causal=True, window=cfg.window,
-                            impl=rt.kernel_impl)
-    else:
-        o = st.decode_attention(q, k_cache, v_cache, pos_new, pos_k, cfg_st)
-    out = jnp.einsum("bshk,hkd->bsd", o, wo)
-    return x + out, new_cache
+        with jax.named_scope("kv_write"):
+            pos_k = rt.positions_contig(s_loc)
+            # append the new K/V into its owning shard's slot
+            rank = rt.sp_rank() if rt.mode == "spmd" else 0
+            local_slot = cl - rank * s_loc
+            write = jnp.arange(s_loc)[None] == local_slot[:, None]  # (B, S)
+            if active is not None:
+                write &= active[:, None]
+            write = write[..., None, None]
+            k_cache = jnp.where(write, k_new.astype(cache["k"].dtype),
+                                cache["k"])
+            v_cache = jnp.where(write, v_new.astype(cache["v"].dtype),
+                                cache["v"])
+            new_cache = {"k": k_cache, "v": v_cache}
+        with jax.named_scope("dense"):
+            valid = pos_k[None] <= cl[:, None]                  # (B, S_loc)
+            # hide unfilled slots by pushing their positions beyond the query
+            pos_k = jnp.where(valid, pos_k[None], (cl + 1)[:, None])
+            cfg_st = dataclasses.replace(
+                rt.st_cfg, causal=True, window=cfg.window, prefix_len=None)
+            if rt.mode == "local":
+                o = kernels.prefill(q, k_cache, v_cache, pos_new, pos_k,
+                                    causal=True, window=cfg.window,
+                                    impl=rt.kernel_impl)
+            else:
+                o = st.decode_attention(q, k_cache, v_cache, pos_new, pos_k,
+                                        cfg_st)
+    with jax.named_scope("out"):
+        wo = rt.dense(p["wo"], ("heads", "head_dim", "embed_out"))
+        return x + jnp.einsum("bshk,hkd->bsd", o, wo), new_cache
 
 
 def _mamba_decode(rt: Runtime, p, x, cache, cfg: ModelConfig):
@@ -242,50 +249,65 @@ def lm_decode_step(rt: Runtime, params, cache, tokens, cfg: ModelConfig,
       batched serving draw identical noise).
     """
     pat = transformer.layer_pattern(cfg)
-    x = blocks.embed(rt, params["embed"], tokens, cfg, tokens_replicated=True)
+    with jax.named_scope("decode/embed"):
+        x = blocks.embed(rt, params["embed"], tokens, cfg,
+                         tokens_replicated=True)
 
     def period_fn(x, p_and_cache):
         p, c = p_and_cache
         new_c = {}
         for i, (mixer, mlp) in enumerate(pat):
             sub_p, sub_c = p[f"sub{i}"], c[f"sub{i}"]
-            if mixer == "attn":
-                x, nc = _attn_decode(rt, sub_p["mixer"], x, sub_c, cfg,
-                                     cache_len, paged=paged, active=active)
-            elif mixer == "mamba":
-                x, nc = _mamba_decode(rt, sub_p["mixer"], x, sub_c, cfg)
-            elif mixer == "mlstm":
-                x, nc = _mlstm_decode(rt, sub_p["mixer"], x, sub_c, cfg)
-            else:
-                x, nc = _slstm_decode(rt, sub_p["mixer"], x, sub_c, cfg)
+            with jax.named_scope(mixer):
+                if mixer == "attn":
+                    x, nc = _attn_decode(rt, sub_p["mixer"], x, sub_c, cfg,
+                                         cache_len, paged=paged,
+                                         active=active)
+                elif mixer == "mamba":
+                    x, nc = _mamba_decode(rt, sub_p["mixer"], x, sub_c, cfg)
+                elif mixer == "mlstm":
+                    x, nc = _mlstm_decode(rt, sub_p["mixer"], x, sub_c, cfg)
+                else:
+                    x, nc = _slstm_decode(rt, sub_p["mixer"], x, sub_c, cfg)
             new_c[f"sub{i}"] = nc
-            if mlp == "mlp":
-                x = blocks.mlp_block(rt, sub_p["mlp"], x, cfg)
-            elif mlp == "moe":
-                x, _ = moe_lib.moe_block(rt, sub_p["mlp"], x, cfg)
+            x = _mlp(rt, mlp, sub_p, x, cfg)
         return x, new_c
 
     n_p = jax.tree.leaves(params["stack"])[0].shape[0]
-    x, new_subs = jax.lax.scan(period_fn, x, (params["stack"], cache["stack"]),
-                               unroll=n_p if rt.unroll_scans else 1)
-    x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params.get("lm_head", params["embed"])
-    if sampling is None:
-        next_tok = vocab_parallel_greedy(rt, head, x, cfg)
-    else:
-        from repro.engine import sampling as sampling_lib
+    with jax.named_scope("decode/layers"):
+        x, new_subs = jax.lax.scan(
+            period_fn, x, (params["stack"], cache["stack"]),
+            unroll=n_p if rt.unroll_scans else 1)
+    with jax.named_scope("decode/head"):
+        x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = params.get("lm_head", params["embed"])
+        if sampling is None:
+            next_tok = vocab_parallel_greedy(rt, head, x, cfg)
+        else:
+            from repro.engine import sampling as sampling_lib
 
-        cl = jnp.asarray(cache_len, jnp.int32)
-        if cl.ndim == 0:
-            cl = jnp.broadcast_to(cl, (x.shape[0],))
-        # key the noise by the sampled token's *position* so a request's
-        # sample stream is independent of slot/step placement
-        keys = jax.vmap(jax.random.fold_in)(sampling["keys"], cl + 1)
-        next_tok = sampling_lib.sample(
-            rt, head, x, cfg, temperature=sampling["temperature"],
-            top_k=sampling["top_k"], top_p=sampling["top_p"], keys=keys,
-            sc=sampling.get("sc", sampling_lib.SamplingConfig()))
+            cl = jnp.asarray(cache_len, jnp.int32)
+            if cl.ndim == 0:
+                cl = jnp.broadcast_to(cl, (x.shape[0],))
+            # key the noise by the sampled token's *position* so a request's
+            # sample stream is independent of slot/step placement
+            keys = jax.vmap(jax.random.fold_in)(sampling["keys"], cl + 1)
+            next_tok = sampling_lib.sample(
+                rt, head, x, cfg, temperature=sampling["temperature"],
+                top_k=sampling["top_k"], top_p=sampling["top_p"], keys=keys,
+                sc=sampling.get("sc", sampling_lib.SamplingConfig()))
     return next_tok, {"stack": new_subs}
+
+
+def _mlp(rt: Runtime, kind: Optional[str], p, x, cfg: ModelConfig):
+    """A period's channel mixer (``p["mlp"]``), if it has one, under the
+    ``mlp`` / ``moe`` scope."""
+    if kind not in ("mlp", "moe"):
+        return x
+    with jax.named_scope(kind):
+        if kind == "mlp":
+            return blocks.mlp_block(rt, p["mlp"], x, cfg)
+        return moe_lib.moe_block(rt, p["mlp"], x, cfg)[0]
 
 
 def vocab_parallel_greedy(rt: Runtime, head_params, x, cfg: ModelConfig):
@@ -393,46 +415,55 @@ def lm_prefill(rt: Runtime, params, batch, cfg: ModelConfig,
     """
     pat = transformer.layer_pattern(cfg)
     tokens = batch["tokens"]
-    x = blocks.embed(rt, params["embed"], tokens, cfg)
     prefix_len = None
-    if cfg.frontend_stub is not None and "frontend_emb" in batch:
-        prefix_len = int(cfg.prefix_len_frac * rt.st_cfg.seq_len)
-        pos = rt.positions(tokens.shape[1])
-        is_prefix = (pos < prefix_len)[None, :, None]
-        x = jnp.where(is_prefix, batch["frontend_emb"].astype(x.dtype), x)
+    with jax.named_scope("prefill/embed"):
+        x = blocks.embed(rt, params["embed"], tokens, cfg)
+        if cfg.frontend_stub is not None and "frontend_emb" in batch:
+            prefix_len = int(cfg.prefix_len_frac * rt.st_cfg.seq_len)
+            pos = rt.positions(tokens.shape[1])
+            is_prefix = (pos < prefix_len)[None, :, None]
+            x = jnp.where(is_prefix, batch["frontend_emb"].astype(x.dtype),
+                          x)
 
     def period_fn(x, p):
         caches = {}
         for i, (mixer, mlp) in enumerate(pat):
             sub = p[f"sub{i}"]
-            if mixer == "attn":
-                x, (k, v) = blocks.attention_block(
-                    rt, sub["mixer"], x, cfg, causal=True, window=cfg.window,
-                    prefix_len=prefix_len, return_kv=True)
-                caches[f"sub{i}"] = {"k": k, "v": v}
-            elif mixer == "mamba":
-                x, st_c = ssm.mamba_block(rt, sub["mixer"], x, cfg,
-                                          return_state=True)
-                caches[f"sub{i}"] = st_c
-            elif mixer == "mlstm":
-                x, st_c = ssm.mlstm_block(rt, sub["mixer"], x, cfg,
-                                          return_state=True)
-                caches[f"sub{i}"] = st_c
-            else:
-                x, st_c = ssm.slstm_block(rt, sub["mixer"], x, cfg,
-                                          return_state=True)
-                caches[f"sub{i}"] = st_c
-            if mlp == "mlp":
-                x = blocks.mlp_block(rt, sub["mlp"], x, cfg)
-            elif mlp == "moe":
-                x, _ = moe_lib.moe_block(rt, sub["mlp"], x, cfg)
+            with jax.named_scope(mixer):
+                if mixer == "attn":
+                    x, (k, v) = blocks.attention_block(
+                        rt, sub["mixer"], x, cfg, causal=True,
+                        window=cfg.window, prefix_len=prefix_len,
+                        return_kv=True)
+                    caches[f"sub{i}"] = {"k": k, "v": v}
+                elif mixer == "mamba":
+                    x, caches[f"sub{i}"] = ssm.mamba_block(
+                        rt, sub["mixer"], x, cfg, return_state=True)
+                elif mixer == "mlstm":
+                    x, caches[f"sub{i}"] = ssm.mlstm_block(
+                        rt, sub["mixer"], x, cfg, return_state=True)
+                else:
+                    x, caches[f"sub{i}"] = ssm.slstm_block(
+                        rt, sub["mixer"], x, cfg, return_state=True)
+            x = _mlp(rt, mlp, sub, x, cfg)
         return x, caches
 
     n_p = jax.tree.leaves(params["stack"])[0].shape[0]
-    x, cache = jax.lax.scan(period_fn, x, params["stack"],
-                            unroll=n_p if rt.unroll_scans else 1)
+    with jax.named_scope("prefill/layers"):
+        x, cache = jax.lax.scan(period_fn, x, params["stack"],
+                                unroll=n_p if rt.unroll_scans else 1)
+    with jax.named_scope("prefill/head"):
+        last = _prefill_last(rt, params, x, cfg, prompt_len)
+        if return_hidden:
+            return last, {"stack": cache}
+        head = params.get("lm_head", params["embed"])
+        return vocab_parallel_greedy(rt, head, last, cfg), {"stack": cache}
+
+
+def _prefill_last(rt: Runtime, params, x, cfg: ModelConfig, prompt_len):
+    """The final-normed hidden state of each sequence's last prompt
+    position, (B, 1, D), replicated across SP."""
     x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params.get("lm_head", params["embed"])
     if prompt_len is None:
         # next token from the LAST position: the last SP shard's final slot
         # (contiguous layout); broadcast its hidden state then sample.
@@ -452,10 +483,7 @@ def lm_prefill(rt: Runtime, params, batch, cfg: ModelConfig,
         if rt.mode == "spmd":
             last = jax.lax.psum(last, rt.sp_axes)
         last = last.astype(x.dtype)
-    if return_hidden:
-        return last, {"stack": cache}
-    next_tok = vocab_parallel_greedy(rt, head, last, cfg)
-    return next_tok, {"stack": cache}
+    return last
 
 
 def _attn_prefill_paged(rt: Runtime, p, x, pool_sub, cfg: ModelConfig,
@@ -494,63 +522,65 @@ def _attn_prefill_paged(rt: Runtime, p, x, pool_sub, cfg: ModelConfig,
     sp = rt.sp_size()
     rank = rt.sp_rank()
     ps = page_size
-    h = blocks.rmsnorm(p["norm"], x, cfg.norm_eps)
-    wq = rt.dense(p["wq"], ("embed", "heads", "head_dim"))
-    wk = rt.dense(p["wk"], ("embed", "kv_heads", "head_dim"))
-    wv = rt.dense(p["wv"], ("embed", "kv_heads", "head_dim"))
-    wo = rt.dense(p["wo"], ("heads", "head_dim", "embed_out"))
-
-    pos_loc = cached_len + rt.positions_contig(S_loc)           # (S_loc,)
-    q = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wq), pos_loc,
-                    cfg.rope_theta)
-    k = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wk), pos_loc,
-                    cfg.rope_theta)
-    v = jnp.einsum("bsd,dhk->bshk", h, wv)
-
-    kg = rt.all_gather_model(k, axis=1)              # (1, S_b, Hkv, hd)
-    vg = rt.all_gather_model(v, axis=1)
-    qg = rt.all_gather_model(q, axis=1)              # (1, S_b, Hq, hd)
+    with jax.named_scope("qkv"):
+        h = blocks.rmsnorm(p["norm"], x, cfg.norm_eps)
+        wq = rt.dense(p["wq"], ("embed", "heads", "head_dim"))
+        wk = rt.dense(p["wk"], ("embed", "kv_heads", "head_dim"))
+        wv = rt.dense(p["wv"], ("embed", "kv_heads", "head_dim"))
+        pos_loc = cached_len + rt.positions_contig(S_loc)       # (S_loc,)
+        q = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wq), pos_loc,
+                        cfg.rope_theta)
+        k = blocks.rope(jnp.einsum("bsd,dhk->bshk", h, wk), pos_loc,
+                        cfg.rope_theta)
+        v = jnp.einsum("bsd,dhk->bshk", h, wv)
+        kg = rt.all_gather_model(k, axis=1)          # (1, S_b, Hkv, hd)
+        vg = rt.all_gather_model(v, axis=1)
+        qg = rt.all_gather_model(q, axis=1)          # (1, S_b, Hq, hd)
     S_b = S_loc * sp
-    pos_all = cached_len + jnp.arange(S_b, dtype=jnp.int32)
-
-    # --- cached-prefix partial: every suffix query vs this shard's pages
     tbl = jax.lax.dynamic_index_in_dim(table_row, rank, axis=0,
                                        keepdims=False)          # (W,)
     W = tbl.shape[0]
     pages_loc = pool_sub["k"].shape[0]
-    o_pre, lse_pre = kernels.paged_prefill(
-        qg, pool_sub["k"], pool_sub["v"], tbl[None],
-        jnp.reshape(cached_len, (1,)).astype(jnp.int32), rank, sp=sp,
-        page_size=ps, window=cfg.window, impl=rt.kernel_impl)
-    o_pre, lse_pre = st.combine_partials_with_lse(o_pre, lse_pre,
-                                                  rt.sp_axes)
-    lo = rank * S_loc
-    o_pre = jax.lax.dynamic_slice_in_dim(o_pre, lo, S_loc, axis=1)
-    lse_pre = jax.lax.dynamic_slice_in_dim(lse_pre, lo, S_loc, axis=2)
 
-    # --- suffix self-attention partial (local queries, gathered keys)
-    o_suf, lse_suf = kernels.block_fwd(
-        q, kg, vg, pos_loc, pos_all, causal=True, window=cfg.window,
-        impl=rt.kernel_impl)
-    o, _ = combine.combine_pair(o_pre, lse_pre, o_suf, lse_suf)
-    x = x + jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), wo)
+    with jax.named_scope("paged"):
+        # --- cached-prefix partial: every suffix query vs this shard's pages
+        o_pre, lse_pre = kernels.paged_prefill(
+            qg, pool_sub["k"], pool_sub["v"], tbl[None],
+            jnp.reshape(cached_len, (1,)).astype(jnp.int32), rank, sp=sp,
+            page_size=ps, window=cfg.window, impl=rt.kernel_impl)
+        o_pre, lse_pre = st.combine_partials_with_lse(o_pre, lse_pre,
+                                                      rt.sp_axes)
+        lo = rank * S_loc
+        o_pre = jax.lax.dynamic_slice_in_dim(o_pre, lo, S_loc, axis=1)
+        lse_pre = jax.lax.dynamic_slice_in_dim(lse_pre, lo, S_loc, axis=2)
 
-    # --- scatter the suffix K/V into this shard's owned pages
-    G = S_b // ps
-    kb = kg[0].reshape(G, ps, *kg.shape[2:])
-    vb = vg[0].reshape(G, ps, *vg.shape[2:])
-    start_block = cached_len // ps                   # cached_len % ps == 0
-    gidx = jnp.arange(G, dtype=jnp.int32)
-    gglob = start_block + gidx
-    j = gglob // sp
-    page = tbl[jnp.clip(j, 0, W - 1)]
-    mine = ((gglob % sp) == rank) & (gidx * ps < prompt_len - cached_len) \
-        & (j < W) & (page >= 0)
-    page = jnp.where(mine, page, pages_loc)          # OOB -> drop
-    pool_k = pool_sub["k"].at[page].set(kb.astype(pool_sub["k"].dtype),
-                                        mode="drop")
-    pool_v = pool_sub["v"].at[page].set(vb.astype(pool_sub["v"].dtype),
-                                        mode="drop")
+        # --- suffix self-attention partial (local queries, gathered keys)
+        pos_all = cached_len + jnp.arange(S_b, dtype=jnp.int32)
+        o_suf, lse_suf = kernels.block_fwd(
+            q, kg, vg, pos_loc, pos_all, causal=True, window=cfg.window,
+            impl=rt.kernel_impl)
+        o, _ = combine.combine_pair(o_pre, lse_pre, o_suf, lse_suf)
+    with jax.named_scope("out"):
+        wo = rt.dense(p["wo"], ("heads", "head_dim", "embed_out"))
+        x = x + jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), wo)
+
+    with jax.named_scope("kv_write"):
+        # --- scatter the suffix K/V into this shard's owned pages
+        G = S_b // ps
+        kb = kg[0].reshape(G, ps, *kg.shape[2:])
+        vb = vg[0].reshape(G, ps, *vg.shape[2:])
+        start_block = cached_len // ps               # cached_len % ps == 0
+        gidx = jnp.arange(G, dtype=jnp.int32)
+        gglob = start_block + gidx
+        j = gglob // sp
+        page = tbl[jnp.clip(j, 0, W - 1)]
+        mine = ((gglob % sp) == rank) \
+            & (gidx * ps < prompt_len - cached_len) & (j < W) & (page >= 0)
+        page = jnp.where(mine, page, pages_loc)      # OOB -> drop
+        pool_k = pool_sub["k"].at[page].set(kb.astype(pool_sub["k"].dtype),
+                                            mode="drop")
+        pool_v = pool_sub["v"].at[page].set(vb.astype(pool_sub["v"].dtype),
+                                            mode="drop")
     return x, {"k": pool_k, "v": pool_v}
 
 
@@ -575,7 +605,8 @@ def lm_prefill_paged(rt: Runtime, params, batch, cfg: ModelConfig, *,
     cl = jnp.asarray(prompt_len, jnp.int32)[0]
     cc = jnp.asarray(cached_len, jnp.int32)[0]
     tokens = batch["tokens"]
-    x = blocks.embed(rt, params["embed"], tokens, cfg)
+    with jax.named_scope("prefill/embed"):
+        x = blocks.embed(rt, params["embed"], tokens, cfg)
 
     def period_fn(x, p_and_pool):
         p, pool = p_and_pool
@@ -585,25 +616,29 @@ def lm_prefill_paged(rt: Runtime, params, batch, cfg: ModelConfig, *,
             # MoE is unreachable too: Engine rejects prefix caching for MoE
             # stacks (capacity couples prefix KV to the suffix)
             assert mlp != "moe", "prefix-cached prefill excludes MoE"
-            x, new_pool[f"sub{i}"] = _attn_prefill_paged(
-                rt, p[f"sub{i}"]["mixer"], x, pool[f"sub{i}"], cfg,
-                cc, cl, table_row, page_size)
-            if mlp == "mlp":
-                x = blocks.mlp_block(rt, p[f"sub{i}"]["mlp"], x, cfg)
+            with jax.named_scope("attn"):
+                x, new_pool[f"sub{i}"] = _attn_prefill_paged(
+                    rt, p[f"sub{i}"]["mixer"], x, pool[f"sub{i}"], cfg,
+                    cc, cl, table_row, page_size)
+            x = _mlp(rt, mlp, p[f"sub{i}"], x, cfg)
         return x, new_pool
 
     n_p = jax.tree.leaves(params["stack"])[0].shape[0]
-    x, new_subs = jax.lax.scan(period_fn, x,
-                               (params["stack"], pools["stack"]),
-                               unroll=n_p if rt.unroll_scans else 1)
-    x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    # last real position prompt_len-1 sits at suffix offset pl-1-cached_len:
-    # one (shard, slot) matches; one-hot contraction + psum broadcasts it
-    target = cl - 1 - cc
-    pos = rt.positions_contig(x.shape[1])
-    onehot = (pos == target).astype(jnp.float32)[None]
-    last = jnp.einsum("bs,bsd->bd", onehot, x.astype(jnp.float32))[:, None]
-    last = jax.lax.psum(last, rt.sp_axes).astype(x.dtype)
+    with jax.named_scope("prefill/layers"):
+        x, new_subs = jax.lax.scan(period_fn, x,
+                                   (params["stack"], pools["stack"]),
+                                   unroll=n_p if rt.unroll_scans else 1)
+    with jax.named_scope("prefill/head"):
+        x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        # last real position prompt_len-1 sits at suffix offset
+        # pl-1-cached_len: one (shard, slot) matches; one-hot contraction +
+        # psum broadcasts it
+        target = cl - 1 - cc
+        pos = rt.positions_contig(x.shape[1])
+        onehot = (pos == target).astype(jnp.float32)[None]
+        last = jnp.einsum("bs,bsd->bd", onehot,
+                          x.astype(jnp.float32))[:, None]
+        last = jax.lax.psum(last, rt.sp_axes).astype(x.dtype)
     return last, {"stack": new_subs}
 
 

@@ -166,15 +166,17 @@ def test_tracer_span_nesting_and_ordering():
 
 
 def test_tracer_async_pairs_and_instant():
+    # an instant inside the lifecycle is an empty sync span
     tr = obs.Tracer(enabled=True)
     sid = tr.async_begin("request", uid="r0")
-    tr.instant("tick")
+    with tr.span("tick"):
+        pass
     tr.async_end("request", sid, tokens=3)
     phs = [e["ph"] for e in tr.events()]
-    assert phs == ["b", "i", "e"]
-    b, _, e = tr.events()
+    assert phs == ["b", "X", "e"]
+    b, tick, e = tr.events()
     assert b["id"] == e["id"] == sid
-    assert b["ts"] <= e["ts"]
+    assert b["ts"] <= tick["ts"] <= tick["ts"] + tick["dur"] <= e["ts"]
 
 
 def test_disabled_tracer_is_a_noop():
@@ -183,7 +185,7 @@ def test_disabled_tracer_is_a_noop():
         pass
     assert tr.async_begin("r") is None
     tr.async_end("r", None)
-    tr.instant("i")
+    tr.async_end("r", "s1")
     assert tr.events() == []
 
 
@@ -247,3 +249,160 @@ def test_span_nesting_under_interleaved_engine_steps():
         assert pair[0]["ts"] <= pair[1]["ts"]
         uids.add(pair[0]["args"]["uid"])
     assert uids == {"e0r0", "e0r1", "e1r0", "e1r1"}
+
+
+# ---------------------------------------------------------------------------
+# host phases of an engine step, and the process's GC / compile counters
+# ---------------------------------------------------------------------------
+
+def _phase_engine(tracer=None):
+    from repro.engine import EngineConfig, build_engine
+
+    ecfg = EngineConfig(max_slots=2, page_size=4, pages_per_shard=32,
+                        max_len=64)
+    return build_engine("h2o-danube-1.8b", smoke=True, c=1, data=1,
+                        eng=ecfg, tracer=tracer)
+
+
+def _requests(eng, n, new=6):
+    import numpy as np
+
+    from repro.engine import Request
+
+    rng = np.random.default_rng(5)
+    for j in range(n):
+        eng.add_request(Request(
+            uid=f"r{j}", tokens=rng.integers(0, eng.cfg.vocab_size,
+                                             5 + j).tolist(),
+            max_new_tokens=new))
+
+
+def _inside(e, outer):
+    return outer["ts"] <= e["ts"] and \
+        e["ts"] + e["dur"] <= outer["ts"] + outer["dur"]
+
+
+def test_engine_phase_spans_nest():
+    tracer = obs.Tracer(enabled=True)
+    eng = _phase_engine(tracer)
+    _requests(eng, 3)
+    eng.run()
+    events = [e for e in tracer.events() if e["ph"] == "X"]
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    assert {"engine/admit", "engine/decode_inputs", "engine/decode_launch",
+            "engine/decode_wait", "engine/prefill_wait",
+            "engine/emit"} <= set(by)
+    for name, parent in (("engine/decode_inputs", "engine/decode"),
+                         ("engine/decode_launch", "engine/decode"),
+                         ("engine/decode_wait", "engine/decode"),
+                         ("engine/prefill_wait", "engine/prefill"),
+                         ("engine/admit", "engine/step"),
+                         ("engine/emit", "engine/step")):
+        for e in by[name]:
+            assert sum(_inside(e, p) for p in by[parent]) == 1, name
+    # the three decode phases run in order inside each decode span
+    for d in by["engine/decode"]:
+        kids = sorted((e for n in ("engine/decode_inputs",
+                                   "engine/decode_launch",
+                                   "engine/decode_wait")
+                       for e in by[n] if _inside(e, d)),
+                      key=lambda e: e["ts"])
+        assert [e["name"] for e in kids] == [
+            "engine/decode_inputs", "engine/decode_launch",
+            "engine/decode_wait"]
+
+
+def test_engine_phase_counters_cover_the_step():
+    import time
+
+    from repro.engine.engine import PHASES
+
+    eng = _phase_engine()
+    _requests(eng, 2, new=4)
+    eng.run()                                   # compile every shape first
+    eng.reset()
+    _requests(eng, 2, new=24)
+    wall, phases = 0.0, dict.fromkeys(PHASES, 0.0)
+    while not eng.idle():
+        t = time.perf_counter()
+        eng.step()
+        wall += time.perf_counter() - t
+        assert set(eng.last_step_phases) == set(PHASES)
+        for k, v in eng.last_step_phases.items():
+            phases[k] += v
+    assert sum(phases.values()) == pytest.approx(wall, rel=0.05)
+    assert phases["decode_wait"] > 0 and phases["prefill_wait"] > 0
+    # folded into the engine's registry once per step, reset with it
+    assert eng.metrics.phase_seconds() == pytest.approx(phases)
+    text = eng.registry.render_prometheus()
+    assert 'engine_phase_seconds_total{phase="decode_wait"}' in text
+    eng.reset()
+    assert sum(eng.metrics.phase_seconds().values()) == 0.0
+
+
+def test_gc_collect_raises_the_gc_counters():
+    import gc
+
+    watch = obs.watch_host()
+    before = watch.snapshot()
+    gc.collect()
+    after = watch.snapshot()
+    assert after["gc_gen2"] >= before["gc_gen2"] + 1
+    assert after["gc_s"] > before["gc_s"]
+    watch.fold()
+    reg = obs.global_registry()
+    assert reg.value("host_gc_collections_total", generation=2) == \
+        after["gc_gen2"]
+    assert reg.value("host_gc_seconds_total") == after["gc_s"]
+
+
+def test_a_new_decode_width_raises_the_compile_counters():
+    import numpy as np
+
+    eng = _phase_engine()
+    _requests(eng, 1, new=2)
+    eng.run()
+    before = eng.host.snapshot()
+    width = 2 * eng.scheduler.table_width
+    B = eng.eng.max_slots
+    fn = eng._decode_fn(width, False)
+    fn(eng.params, eng.pools, np.zeros((B, 1), np.int32),
+       np.zeros((B,), np.int32), np.zeros((B, 1, width), np.int32),
+       np.zeros((B,), np.float32), np.zeros((B,), np.int32),
+       np.ones((B,), np.float32), np.zeros((B, 2), np.uint32),
+       np.zeros((B,), bool))
+    after = eng.host.snapshot()
+    assert after["compiles"] > before["compiles"]
+    assert after["compile_s"] > before["compile_s"]
+
+
+def test_host_spans_land_on_the_profiler_clock(tmp_path):
+    """With an annotating tracer, a GC pause and a compile are host spans
+    in the profiler's own trace, beside the engine's."""
+    import gc
+    import pathlib
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from bench import scopes
+
+    tracer = obs.Tracer(enabled=True, annotate=True)
+    watch = obs.watch_host(tracer)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        with jax.profiler.TraceAnnotation("bench/window"):
+            gc.collect()
+            jax.jit(lambda x: x * 3 + 1)(jnp.ones((7, 3)))
+        jax.profiler.stop_trace()
+    finally:
+        watch.tracer = obs.NULL_TRACER
+    [path] = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    tl = scopes.read(path, 0)
+    names = {n for s, e, n in tl.host
+             if tl.window[0] <= s and e <= tl.window[1]}
+    assert {"host/gc", "host/compile"} <= names

@@ -3,10 +3,11 @@ cache, compiled once per length bucket.
 
 The engine owns three kinds of state:
 
-  * **device** — the page pools (``paged_cache.init_pools``) and the model
-    params, both living in the refined ``(data, sp_grp, sp_ring, sp_team)``
-    mesh's shardings — the mesh, the (C, R) refinement and the paged-decode
-    ``kernel_impl`` all come from one ``ExecutionPlan`` serve plan
+  * **device** — the page pools (``paged_cache.zeroed_pools_fn``) and the
+    model params, both living in the refined
+    ``(data, sp_grp, sp_ring, sp_team)`` mesh's shardings — the mesh, the
+    (C, R) refinement and the paged-decode ``kernel_impl`` all come from
+    one ``ExecutionPlan`` serve plan
     (``plan.make_serve_plan`` / ``launch.serve --plan``);
   * **host** — the ``Scheduler`` (slots, page free lists, page table,
     FIFO queue);
@@ -236,11 +237,8 @@ class Engine:
                  labels: Optional[Dict[str, str]] = None,
                  tracer: Optional[obs.Tracer] = None, seed: int = 0):
         import jax
-        import jax.numpy as jnp
         import dataclasses as dc
         from jax.sharding import NamedSharding
-
-        from repro.train import step as train_step
 
         cfg = model.cfg
         ok, why = paged_cache.supported(cfg)
@@ -269,11 +267,7 @@ class Engine:
         if self.sp != plan.sp_size:
             raise ValueError(f"mesh SP degree {self.sp} != plan "
                              f"sp_size {plan.sp_size}")
-        shape = plan.shape_config()
-        rt = train_step.make_runtime(model, run_cfg, shape, mode="spmd")
-        rt = dc.replace(rt, batch_axes=(),
-                        st_cfg=dc.replace(rt.st_cfg, seq_scheme="contiguous"))
-        self.rt = rt
+        self.rt = serve_runtime(model, plan)
         self.kernel_impl = plan.kernel_impl
         self._param_specs = model.partition(run_cfg.sharding_rules)
         # params live in the layout the step islands read them in: created
@@ -314,17 +308,13 @@ class Engine:
         self._arrival: Dict[str, float] = {}     # uid -> enqueue time
         self._last_emit: Dict[str, float] = {}   # uid -> last token time
         self._req_spans: Dict[str, Optional[str]] = {}
-        # all pool (re)initialisation goes through one jitted zeroing fn so
-        # every pool entering a step fn is a jit output — device_put arrays
-        # carry a differently-typed sharding and would retrace the first
-        # call after each reset()
-        self._zero_pools = jax.jit(jax.shard_map(
-            lambda pools: jax.tree.map(jnp.zeros_like, pools),
-            mesh=mesh, in_specs=(self._pool_part,),
-            out_specs=self._pool_part, check_vma=False),
-            donate_argnums=(0,))
-        self.pools = self._zero_pools(paged_cache.init_pools(
-            cfg, mesh, self.sp * eng.pages_per_shard, eng.page_size))
+        # the pools are made on the devices, by one jitted fn: every pool
+        # entering a step fn is a jit output — device_put arrays carry a
+        # differently-typed sharding and would retrace the first call
+        # after each reset()
+        self._new_pools = paged_cache.zeroed_pools_fn(
+            cfg, mesh, self.sp * eng.pages_per_shard, eng.page_size)
+        self.pools = self._new_pools()
         self.prefix_caching = bool(getattr(plan, "prefix_cache", False))
         if self.prefix_caching and any(
                 mlp == "moe" for _, mlp in transformer.layer_pattern(cfg)):
@@ -543,7 +533,13 @@ class Engine:
         """Drop all requests and cache contents (including the prefix
         cache and the host tier — the pools are zeroed); keep compiled
         fns."""
-        self.pools = self._zero_pools(self.pools)
+        import jax
+
+        # free the pools before making their zeroed successor, so the
+        # devices never hold two
+        for leaf in jax.tree.leaves(self.pools):
+            leaf.delete()
+        self.pools = self._new_pools()
         self.connector.reset()
         self.scheduler = self._new_scheduler()
         self._prefilling = []
@@ -671,32 +667,11 @@ class Engine:
 
     def _decode_fn(self, width: int, sampled: bool):
         """One jit per (table-width bucket, any-active-request-samples)."""
-        import jax
-        from jax.sharding import PartitionSpec as P
-
-        from repro.serve import step as serve_step
-
         fn = self._decode_fns.get((width, sampled))
         if fn is not None:
             return fn
-        cfg, eng, rt, sc = self.cfg, self.eng, self.rt, self._sc
-
-        def island(params, pools, tokens, cache_len, table,
-                   temp, top_k, top_p, keys, active):
-            paged = paged_cache.PagedTables(table=table,
-                                            page_size=eng.page_size)
-            sampling = {"temperature": temp, "top_k": top_k, "top_p": top_p,
-                        "keys": keys, "sc": sc} if sampled else None
-            return serve_step.lm_decode_step(
-                rt, params, pools, tokens, cfg, cache_len, paged=paged,
-                active=active, sampling=sampling)
-
-        fn = jax.jit(jax.shard_map(
-            island, mesh=self.mesh,
-            in_specs=(self._param_specs, self._pool_part, P(), P(), P(),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(), self._pool_part), check_vma=False),
-            donate_argnums=(1,))
+        fn = decode_step_fn(self.cfg, self.rt, self.mesh, self._param_specs,
+                            self.eng.page_size, sampled, self._sc)
         self._decode_fns[(width, sampled)] = fn
         self.metrics.decode_compiles += 1
         return fn
@@ -1019,6 +994,53 @@ class Engine:
             if n > limit:
                 raise RuntimeError(f"engine did not drain in {limit} steps")
         return self.collect()
+
+
+def serve_runtime(model: Model, plan):
+    """The runtime every engine island traces under: the plan's SPMD
+    runtime with the batch replicated and the caches laid out
+    contiguously."""
+    import dataclasses as dc
+
+    from repro.train import step as train_step
+
+    rt = train_step.make_runtime(model, plan.run_config(),
+                                 plan.shape_config(), mode="spmd")
+    return dc.replace(rt, batch_axes=(),
+                      st_cfg=dc.replace(rt.st_cfg, seq_scheme="contiguous"))
+
+
+def decode_step_fn(cfg, rt, mesh, param_specs, page_size: int,
+                   sampled: bool = False,
+                   sc: Optional[sampling_lib.SamplingConfig] = None):
+    """The engine's jitted decode step over ``mesh``: (params, pools,
+    tokens, cache_len, table, temperature, top_k, top_p, keys, active) ->
+    (next tokens, pools). The pools are donated: the step updates them in
+    place. It needs no live engine, so it also compiles ahead of time for
+    a described chip."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.serve import step as serve_step
+
+    sc = sc if sc is not None else sampling_lib.SamplingConfig()
+    pool_part = paged_cache.pool_partition(cfg)
+
+    def island(params, pools, tokens, cache_len, table,
+               temp, top_k, top_p, keys, active):
+        paged = paged_cache.PagedTables(table=table, page_size=page_size)
+        sampling = {"temperature": temp, "top_k": top_k, "top_p": top_p,
+                    "keys": keys, "sc": sc} if sampled else None
+        return serve_step.lm_decode_step(
+            rt, params, pools, tokens, cfg, cache_len, paged=paged,
+            active=active, sampling=sampling)
+
+    return jax.jit(jax.shard_map(
+        island, mesh=mesh,
+        in_specs=(param_specs, pool_part, P(), P(), P(),
+                  P(), P(), P(), P(), P()),
+        out_specs=(P(), pool_part), check_vma=False),
+        donate_argnums=(1,))
 
 
 def build_engine(arch: str, *, smoke: bool = True, c: Optional[int] = 1,

@@ -4,13 +4,22 @@ Layout
 ------
 Each attention sub-layer owns a pool of fixed-size pages
 
-    k, v : (n_periods, P_sp * pages_per_shard, page_size, Hkv, hd)
+    k, v : (n_periods, P_sp * pages_per_shard, page_size, Hkv, hd_pool)
 
 sharded on the *page* dimension over the concentric SP axes
 ``(sp_grp, sp_ring, sp_team)`` — the same axes (and the same linear rank
 order, ``rank = (g*R + r)*C + t``) that ``serve.kv_cache.cache_partition_for``
 uses for the contiguous decode cache. Inside the decode island every shard
-therefore holds a ``(n_periods, pages_per_shard, page_size, Hkv, hd)`` slice.
+therefore holds a ``(n_periods, pages_per_shard, page_size, Hkv, hd_pool)``
+slice.
+
+``hd_pool`` is the head dim rounded up to whole 128-lane tiles
+(:func:`pool_head_dim`), the pad lanes zero. The TPU compiler stores an
+array row-major only when its minor dim fills whole lane tiles; otherwise
+it moves the page dim minor-most, and every program that hands the pool
+to a paged kernel (which DMAs whole pages, row-major) re-lays the pool
+out. With whole tiles the pool stays in the kernels' layout for good, at
+the price of the pad (80 -> 128 at h2o-danube widths: 1.6x the payload).
 
 A sequence's logical KV blocks (block ``b`` covers token positions
 ``[b*page_size, (b+1)*page_size)``) are distributed **round-robin** over the
@@ -45,11 +54,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import SP_AXES
+from repro.kernels.dispatch import pad_head
 from repro.models import transformer
 from repro.models.runtime import Runtime
 
@@ -142,14 +151,23 @@ def supported(cfg: ModelConfig) -> Tuple[bool, str]:
     return True, ""
 
 
+LANES = 128
+
+
+def pool_head_dim(cfg: ModelConfig) -> int:
+    """The pools' head dim: ``cfg.head_dim_`` rounded up to whole lane
+    tiles."""
+    return -(-cfg.head_dim_ // LANES) * LANES
+
+
 def pool_spec(cfg: ModelConfig, pages_global: int, page_size: int):
     """Abstract pool tree {'stack': {subN: {'k','v'}}} (period-stacked)."""
     dtype = jnp.dtype(cfg.param_dtype)
     pat = transformer.layer_pattern(cfg)
     n_periods = cfg.num_layers // len(pat)
-    hd = cfg.head_dim_
     leaf = jax.ShapeDtypeStruct(
-        (n_periods, pages_global, page_size, cfg.num_kv_heads, hd), dtype)
+        (n_periods, pages_global, page_size, cfg.num_kv_heads,
+         pool_head_dim(cfg)), dtype)
     return {"stack": {f"sub{i}": {"k": leaf, "v": leaf}
                       for i in range(len(pat))}}
 
@@ -162,14 +180,19 @@ def pool_partition(cfg: ModelConfig):
                       for i in range(len(pat))}}
 
 
-def init_pools(cfg: ModelConfig, mesh, pages_global: int, page_size: int):
-    """Concrete zeroed pools, placed with the SP-sharded layout."""
-    spec = pool_spec(cfg, pages_global, page_size)
-    part = pool_partition(cfg)
-    return jax.tree.map(
-        lambda s, p: jax.device_put(jnp.zeros(s.shape, s.dtype),
-                                    NamedSharding(mesh, p)),
-        spec, part)
+def zeroed_pools_fn(cfg: ModelConfig, mesh, pages_global: int,
+                    page_size: int):
+    """A jitted, argument-free maker of zeroed pools: each device makes its
+    own SP shard in place, so the pools come out sharded as every step
+    island returns them."""
+    sp = 1
+    for a in SP_AXES:
+        sp *= mesh.shape[a]
+    local = pool_spec(cfg, pages_global // sp, page_size)
+    return jax.jit(jax.shard_map(
+        lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), local),
+        mesh=mesh, in_specs=(), out_specs=pool_partition(cfg),
+        check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +203,7 @@ def read_pages(rt: Runtime, pools, idx):
     """Gather whole pages by *global* page id, replicated to every device.
 
     pools: the full pool tree's local slices, leaves
-      (n_periods, pages_loc, page_size, Hkv, hd).
+      (n_periods, pages_loc, page_size, Hkv, hd_pool).
     idx: (B,) int32 global page ids (``shard * pages_loc + local_page``);
       -1 pads the fixed transfer bucket (padding reads as zeros).
 
@@ -208,7 +231,7 @@ def write_pages(rt: Runtime, pools, idx, data):
     """Scatter whole pages by global page id (inverse of ``read_pages``).
 
     data: a tree like ``pools`` with leaves (n_periods, B, page_size, Hkv,
-    hd), replicated. Every shard writes only the batch entries whose page
+    hd_pool), replicated. Every shard writes only the batch entries whose page
     it owns; idx -1 (bucket padding) and out-of-range ids drop. This is
     the host->device leg of the connector's reload and of the decode-side
     handoff injection.
@@ -225,22 +248,28 @@ def write_pages(rt: Runtime, pools, idx, data):
     return jax.tree.map(leaf, pools, data)
 
 
-def write_token(rt: Runtime, cache: Dict[str, jax.Array], k_new, v_new,
-                paged: PagedTables, cache_len, active):
-    """Append one token per slot into its owning shard's page.
+def write_token(rt: Runtime, pools: Dict[str, jax.Array], k_new, v_new,
+                paged: PagedTables, cache_len, active, layer):
+    """Append one token per slot into its owning shard's page of period
+    ``layer``.
 
-    cache: {'k','v'} local pool slices (pages_loc, page_size, Hkv, hd).
-    k_new/v_new: (B, 1, Hkv, hd) — post-RoPE K and V of the new token.
+    pools: {'k','v'} this shard's period-stacked pool slices
+      (n_periods, pages_loc, page_size, Hkv, hd_pool); the token lands in
+      place at ``[layer, page, offset]`` (one scatter per leaf — no layer
+      is sliced out of the stack).
+    k_new/v_new: (B, 1, Hkv, hd) — post-RoPE K and V of the new token
+      (zero-padded here to the pools' head dim).
     cache_len: (B,) int32 — the new token's global position.
     active: (B,) bool or None — inactive slots write nothing.
+    layer: traced int32 scalar — the period being decoded.
 
-    Returns (new_cache, tbl) where tbl (B, W) is this shard's slice of the
+    Returns (new_pools, tbl) where tbl (B, W) is this shard's slice of the
     page table — the operand both decode-kernel paths consume (the Pallas
     paged kernel indexes the pool with it directly; the ref path gathers a
     dense view via ``kernels.dispatch.paged_decode(..., impl='ref')``).
     """
-    pool_k, pool_v = cache["k"], cache["v"]
-    pages_loc, ps = pool_k.shape[0], paged.page_size
+    pool_k, pool_v = pools["k"], pools["v"]
+    pages_loc, ps = pool_k.shape[1], paged.page_size
     rank = rt.sp_rank()
     sp = rt.sp_size()
     tbl = jax.lax.dynamic_index_in_dim(paged.table, rank, axis=1,
@@ -256,10 +285,11 @@ def write_token(rt: Runtime, cache: Dict[str, jax.Array], k_new, v_new,
         ok &= active
     page = jnp.where(ok, page, pages_loc)                     # OOB -> drop
     off = cache_len % ps
-    pool_k = pool_k.at[page, off].set(
-        k_new[:, 0].astype(pool_k.dtype), mode="drop")
-    pool_v = pool_v.at[page, off].set(
-        v_new[:, 0].astype(pool_v.dtype), mode="drop")
+    hd_pool = pool_k.shape[-1]
+    pool_k = pool_k.at[layer, page, off].set(
+        pad_head(k_new[:, 0], hd_pool).astype(pool_k.dtype), mode="drop")
+    pool_v = pool_v.at[layer, page, off].set(
+        pad_head(v_new[:, 0], hd_pool).astype(pool_v.dtype), mode="drop")
     return {"k": pool_k, "v": pool_v}, tbl
 
 
@@ -267,7 +297,8 @@ def insert_prompt(rt: Runtime, pools_sub: Dict[str, jax.Array],
                   k_stack, v_stack, table_row, prompt_len, page_size: int):
     """Scatter a prefilled sequence's K/V into this shard's pool pages.
 
-    pools_sub: {'k','v'} local slices (n_periods, pages_loc, ps, Hkv, hd).
+    pools_sub: {'k','v'} local slices (n_periods, pages_loc, ps, Hkv,
+      hd_pool).
     k_stack/v_stack: (n_periods, 1, S_loc, Hkv, hd) — the prefill cache of
       one sequence, SP-sharded contiguously (post-RoPE, as written by
       ``serve.step.lm_prefill``).
@@ -302,8 +333,9 @@ def insert_prompt(rt: Runtime, pools_sub: Dict[str, jax.Array],
     mine = ((gidx % sp) == rank) & (gidx * ps < prompt_len) \
         & (j < W) & (page >= 0)
     page = jnp.where(mine, page, pages_loc)             # OOB -> drop
+    hd_pool = pools_sub["k"].shape[-1]
     pool_k = pools_sub["k"].at[:, page].set(
-        kb.astype(pools_sub["k"].dtype), mode="drop")
+        pad_head(kb, hd_pool).astype(pools_sub["k"].dtype), mode="drop")
     pool_v = pools_sub["v"].at[:, page].set(
-        vb.astype(pools_sub["v"].dtype), mode="drop")
+        pad_head(vb, hd_pool).astype(pools_sub["v"].dtype), mode="drop")
     return {"k": pool_k, "v": pool_v}

@@ -209,33 +209,43 @@ def decode(q, k, v, pos_q, pos_k, *, causal=True, window=None, scale=None,
                      scale=scale, impl=impl)
 
 
-def paged_decode(q, pool_k, pool_v, table, cache_len, rank, *, sp: int,
-                 page_size: int, window=None, scale=None,
+def paged_decode(q, pool_k, pool_v, table, cache_len, rank, layer=0, *,
+                 sp: int, page_size: int, window=None, scale=None,
                  impl="ref") -> Tuple[jax.Array, jax.Array]:
     """One query per row vs this shard's pages -> partial (o, lse).
 
-    q: (B, 1, Hq, D); pool_k/pool_v: (pages_loc, page_size, Hkv, D);
-    table: (B, W) local page ids (-1 = unallocated); cache_len: (B,) the
-    new token's position; rank: traced scalar SP rank. Page ``w`` covers
-    positions ``[(w*sp + rank)*page_size, ...)`` (round-robin layout).
+    q: (B, 1, Hq, D); pool_k/pool_v: (L, pages_loc, page_size, Hkv, Dp)
+    stacked over layer periods and read at ``layer`` (traced scalar), or
+    one layer's (pages_loc, page_size, Hkv, Dp); Dp >= D. table: (B, W)
+    local page ids (-1 = unallocated); cache_len: (B,) the new token's
+    position; rank: traced scalar SP rank. Page ``w`` covers positions
+    ``[(w*sp + rank)*page_size, ...)`` (round-robin layout).
 
     'pallas' streams page-table-indexed tiles through
     ``kernels/paged_decode.py``; 'ref' gathers the pages into a dense
     (B, W*page_size) view and reuses the jnp oracle — bit-for-bit the
-    engine's pre-dispatch behaviour.
+    engine's pre-dispatch behaviour. Neither slices a layer out of the
+    stack: both index it where they read the pages. The pools' head dim
+    may be zero-padded past q's (``engine.paged_cache.pool_head_dim``).
     """
+    D = q.shape[-1]
     if impl == "pallas":
         from repro.kernels import paged_decode as _paged
 
-        return _paged.paged_decode_attention(
-            q, pool_k, pool_v, table, cache_len, rank, sp=sp,
-            page_size=page_size, window=window, scale=scale)
+        o, lse = _paged.paged_decode_attention(
+            pad_head(q, pool_k.shape[-1]), pool_k, pool_v, table,
+            cache_len, rank, layer, sp=sp, page_size=page_size,
+            window=window, scale=D ** -0.5 if scale is None else scale)
+        return o[..., :D], lse
 
-    pages_loc = pool_k.shape[0]
+    if pool_k.ndim == 4:
+        pool_k, pool_v = pool_k[None], pool_v[None]
+    pages_loc = pool_k.shape[1]
     B, W = table.shape
     safe = jnp.clip(table, 0, pages_loc - 1)
-    k_r = pool_k[safe].reshape(B, W * page_size, *pool_k.shape[2:])
-    v_r = pool_v[safe].reshape(B, W * page_size, *pool_v.shape[2:])
+    k_r, v_r = (p[layer, safe, ..., :D] for p in (pool_k, pool_v))
+    k_r = k_r.reshape(B, W * page_size, *k_r.shape[3:])
+    v_r = v_r.reshape(B, W * page_size, *v_r.shape[3:])
     pos = ((jnp.arange(W, dtype=jnp.int32) * sp + rank) * page_size)[:, None] \
         + jnp.arange(page_size, dtype=jnp.int32)[None]
     pos = pos.reshape(W * page_size)
@@ -254,30 +264,35 @@ def paged_prefill(q, pool_k, pool_v, table, cached_len, rank, *, sp: int,
 
     q: (B, Sq, Hq, D) — row b's query i sits at global position
     ``cached_len[b] + i`` (the prompt suffix, bucket-padded);
-    pool_k/pool_v: (pages_loc, page_size, Hkv, D); table: (B, W) local page
-    ids (-1 = unallocated); cached_len: (B,) tokens already in the pool;
-    rank: traced scalar SP rank. Keys at positions ``< cached_len`` are
-    visible (strict — the suffix scores itself through the dense partial),
-    page ``w`` covering ``[(w*sp + rank)*page_size, ...)`` (round-robin).
+    pool_k/pool_v: (pages_loc, page_size, Hkv, Dp), Dp >= D (zero pad);
+    table: (B, W) local page ids (-1 = unallocated); cached_len: (B,)
+    tokens already in the pool; rank: traced scalar SP rank. Keys at
+    positions ``< cached_len`` are visible (strict — the suffix scores
+    itself through the dense partial), page ``w`` covering
+    ``[(w*sp + rank)*page_size, ...)`` (round-robin).
 
     'pallas' streams page-table-indexed tiles through
     ``kernels/paged_prefill.py``; 'ref' gathers the pages into a dense
     (B, W*page_size) view and masks positionally — bit-for-bit the
     suffix prefill's pre-dispatch behaviour.
     """
+    D = q.shape[-1]
     if impl == "pallas":
         from repro.kernels import paged_prefill as _paged_pre
 
-        return _paged_pre.paged_prefill_attention(
-            q, pool_k, pool_v, table, cached_len, rank, sp=sp,
-            page_size=page_size, window=window, scale=scale)
+        o, lse = _paged_pre.paged_prefill_attention(
+            pad_head(q, pool_k.shape[-1]), pool_k, pool_v, table,
+            cached_len, rank, sp=sp, page_size=page_size, window=window,
+            scale=D ** -0.5 if scale is None else scale)
+        return o[..., :D], lse
 
     pages_loc = pool_k.shape[0]
     B, W = table.shape
     Sq = q.shape[1]
     safe = jnp.clip(table, 0, pages_loc - 1)
-    k_r = pool_k[safe].reshape(B, W * page_size, *pool_k.shape[2:])
-    v_r = pool_v[safe].reshape(B, W * page_size, *pool_v.shape[2:])
+    k_r, v_r = (p[safe, ..., :D] for p in (pool_k, pool_v))
+    k_r = k_r.reshape(B, W * page_size, *k_r.shape[3:])
+    v_r = v_r.reshape(B, W * page_size, *v_r.shape[3:])
     pos = ((jnp.arange(W, dtype=jnp.int32) * sp + rank) * page_size)[:, None] \
         + jnp.arange(page_size, dtype=jnp.int32)[None]
     pos = pos.reshape(W * page_size)
@@ -290,6 +305,14 @@ def paged_prefill(q, pool_k, pool_v, table, cached_len, rank, *, sp: int,
     return block_fwd(q, k_r.astype(q.dtype), v_r.astype(q.dtype), pos_q,
                      pos_k, causal=True, window=window, scale=scale,
                      impl="ref")
+
+
+def pad_head(x, width: int):
+    """``x`` with its last (head) dim zero-padded to ``width``: the page
+    pools' head dim (``engine.paged_cache.pool_head_dim``). Zero lanes of
+    q add nothing to a score; the output's are cut off."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 # ---------------------------------------------------------------------------

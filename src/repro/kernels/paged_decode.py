@@ -7,10 +7,16 @@ reference path — one full copy of the cache through HBM per decode step),
 the page table is handed to the kernel as a *scalar-prefetch* operand and
 the ``BlockSpec`` index map DMAs each K/V page straight from the pool:
 
-    pool_k, pool_v : (pages_loc, page_size, Hkv, D)   this shard's pool slice
+    pool_k, pool_v : (L, pages_loc, page_size, Hkv, D) this shard's pools,
+                     stacked over the L layer periods
     table          : (B, W) int32                     local page ids, -1 = unallocated
     cache_len      : (B,) int32                       the new token's position
     rank           : (1,) int32                       this shard's SP rank (traced)
+    layer          : (1,) int32                       the period to read (traced)
+
+The layer index rides with the other scalars, so the decode step's layer
+scan hands the kernel the whole stacked pool and its counter: no layer's
+pool is sliced out and written back around the call.
 
 Grid ``(B, W)`` with the page dimension innermost. Each step DMAs one whole
 page ``(page_size, Hkv, D)`` — its last two dims span the pool's, as the
@@ -47,13 +53,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.combine import NEG_INF
 
 
-def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
+def _kernel(tbl_ref, cl_ref, rank_ref, layer_ref,       # scalar prefetch
             q_ref, k_ref, v_ref,                        # inputs
             o_ref, lse_ref,                             # outputs
             acc_ref, m_ref, l_ref,                      # scratch
             *, sp, page_size, window, scale, n_w, n_kv):
     b = pl.program_id(0)
     w = pl.program_id(1)
+    del layer_ref                                        # read by the DMAs
 
     @pl.when(w == 0)
     def _init():
@@ -112,13 +119,15 @@ def _kernel(tbl_ref, cl_ref, rank_ref,                  # scalar prefetch
     static_argnames=("sp", "page_size", "window", "scale", "interpret"),
 )
 def paged_decode_attention(
-    q, pool_k, pool_v, table, cache_len, rank, *, sp, page_size,
+    q, pool_k, pool_v, table, cache_len, rank, layer=0, *, sp, page_size,
     window=None, scale=None, interpret=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-shard paged decode attention -> partial (o, lse).
 
-    q: (B, 1, Hq, D); pool_k/pool_v: (pages_loc, page_size, Hkv, D);
-    table: (B, W) int32; cache_len: (B,) int32; rank: (1,) int32 (traced —
+    q: (B, 1, Hq, D); pool_k/pool_v: (L, pages_loc, page_size, Hkv, D),
+    read at period ``layer`` (traced scalar), or one layer's
+    (pages_loc, page_size, Hkv, D) (L = 1, ``layer`` 0); table: (B, W)
+    int32; cache_len: (B,) int32; rank: (1,) int32 (traced —
     ``jax.lax.axis_index`` products are fine). Page ``w`` of row ``b``
     covers global positions ``[(w*sp + rank)*page_size, ... + page_size)``
     — the round-robin layout of ``engine.paged_cache``.
@@ -126,7 +135,9 @@ def paged_decode_attention(
     B, M, Hq, D = q.shape
     if M != 1:
         raise ValueError(f"paged decode takes one query per row, got M={M}")
-    pages_loc, ps, Hkv, _ = pool_k.shape
+    if pool_k.ndim == 4:
+        pool_k, pool_v = pool_k[None], pool_v[None]
+    _, pages_loc, ps, Hkv, _ = pool_k.shape
     if ps != page_size:
         raise ValueError(f"pool page size {ps} != page_size {page_size}")
     G = Hq // Hkv
@@ -140,25 +151,25 @@ def paged_decode_attention(
         _kernel, sp=sp, page_size=page_size, window=window, scale=scale,
         n_w=W, n_kv=Hkv)
 
-    def page_idx(b, w, tbl, cl, rk):
+    def page_idx(b, w, tbl, cl, rk, ly):
         # -1 (unallocated) clips to page 0; the kernel masks it via pl.when
         del cl, rk
-        return (jnp.maximum(tbl[b, w], 0), 0, 0, 0)
+        return (ly[0], jnp.maximum(tbl[b, w], 0), 0, 0, 0)
 
-    def row_idx(b, w, tbl, cl, rk):
-        del w, tbl, cl, rk
+    def row_idx(b, w, tbl, cl, rk, ly):
+        del w, tbl, cl, rk, ly
         return (b, 0, 0, 0)
 
     # every head of a row in one tile: the query heads grouped by their K/V
     # head, (B, Hkv, G, D), and whole pages, whose last two dims (Hkv, D)
     # span the pool's — one DMA per page serves all heads
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, W),
         in_specs=[
             pl.BlockSpec((1, Hkv, G, D), row_idx),
-            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
-            pl.BlockSpec((1, page_size, Hkv, D), page_idx),
+            pl.BlockSpec((pl.squeezed, 1, page_size, Hkv, D), page_idx),
+            pl.BlockSpec((pl.squeezed, 1, page_size, Hkv, D), page_idx),
         ],
         out_specs=[
             pl.BlockSpec((1, Hkv, G, D), row_idx),
@@ -186,6 +197,7 @@ def paged_decode_attention(
         interpret=interpret,
         **params,
     )(table.astype(jnp.int32), cache_len.astype(jnp.int32),
-      jnp.asarray(rank, jnp.int32).reshape(1), q.reshape(B, Hkv, G, D),
+      jnp.asarray(rank, jnp.int32).reshape(1),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(B, Hkv, G, D),
       pool_k, pool_v)
     return o.reshape(B, 1, Hq, D), lse.reshape(B, Hq, 1)

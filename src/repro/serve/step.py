@@ -41,15 +41,16 @@ from repro.train import step as train_step
 # ---------------------------------------------------------------------------
 
 def _attn_decode(rt: Runtime, p, x, cache, cfg: ModelConfig, cache_len,
-                 paged=None, active=None):
+                 paged=None, active=None, layer=None):
     """x: (B, 1, D) replicated over SP.
 
     cache_len: static int (whole batch at one length — the classic decode
       path) or a traced (B,) int32 array of per-sequence lengths (the
       engine's continuously-batched path).
     cache: contiguous k/v slices (B, S_loc, Hkv, hd), or — when ``paged``
-      is an ``engine.paged_cache.PagedTables`` — this shard's page-pool
-      slices (pages_loc, page_size, Hkv, hd).
+      is an ``engine.paged_cache.PagedTables`` — this shard's whole
+      period-stacked page pools (n_periods, pages_loc, page_size, Hkv,
+      hd_pool), written and read at period ``layer`` (traced scalar).
     active: optional (B,) bool; inactive slots write nothing (engine slots
       between requests).
     """
@@ -79,11 +80,11 @@ def _attn_decode(rt: Runtime, p, x, cache, cfg: ModelConfig, cache_len,
 
         with jax.named_scope("kv_write"):
             new_cache, tbl = paged_lib.write_token(
-                rt, cache, k_new, v_new, paged, cl, active)
+                rt, cache, k_new, v_new, paged, cl, active, layer)
         with jax.named_scope("paged"):
             o_p, lse_p = kernels.paged_decode(
                 q, new_cache["k"], new_cache["v"], tbl, cl, rt.sp_rank(),
-                sp=rt.sp_size(), page_size=paged.page_size,
+                layer, sp=rt.sp_size(), page_size=paged.page_size,
                 window=cfg.window, impl=rt.kernel_impl)
             o = st.combine_decode_partials(
                 o_p, lse_p, rt.sp_axes).astype(x.dtype)
@@ -241,7 +242,12 @@ def lm_decode_step(rt: Runtime, params, cache, tokens, cfg: ModelConfig,
 
     cache_len: static int, or (B,) traced per-sequence lengths (engine).
     paged: ``engine.paged_cache.PagedTables`` — attention caches are page
-      pools instead of contiguous slices (SSM states stay slot-batched).
+      pools instead of contiguous slices. Only all-attention stacks page
+      (``paged_cache.supported``); their period-stacked pools ride in the
+      layer scan's carry and each period writes and reads them in place at
+      its scan index, so no step slices a period's pool out of the stack
+      and writes it back. The contiguous caches (and SSM states) are
+      per-slot and small, and scan as ``xs``/``ys``.
     active: (B,) bool — engine slots currently serving a request.
     sampling: None for greedy, or a dict {temperature, top_k, top_p, keys}
       of per-sequence (B,)-shaped arrays ((B, 2) for keys — PRNG keys *not*
@@ -261,8 +267,7 @@ def lm_decode_step(rt: Runtime, params, cache, tokens, cfg: ModelConfig,
             with jax.named_scope(mixer):
                 if mixer == "attn":
                     x, nc = _attn_decode(rt, sub_p["mixer"], x, sub_c, cfg,
-                                         cache_len, paged=paged,
-                                         active=active)
+                                         cache_len, active=active)
                 elif mixer == "mamba":
                     x, nc = _mamba_decode(rt, sub_p["mixer"], x, sub_c, cfg)
                 elif mixer == "mlstm":
@@ -273,11 +278,31 @@ def lm_decode_step(rt: Runtime, params, cache, tokens, cfg: ModelConfig,
             x = _mlp(rt, mlp, sub_p, x, cfg)
         return x, new_c
 
+    def paged_period_fn(carry, p_and_layer):
+        x, pools = carry
+        p, layer = p_and_layer
+        pools = dict(pools)
+        for i, (mixer, mlp) in enumerate(pat):
+            assert mixer == "attn", "paged decode covers attention mixers"
+            with jax.named_scope(mixer):
+                x, pools[f"sub{i}"] = _attn_decode(
+                    rt, p[f"sub{i}"]["mixer"], x, pools[f"sub{i}"], cfg,
+                    cache_len, paged=paged, active=active, layer=layer)
+            x = _mlp(rt, mlp, p[f"sub{i}"], x, cfg)
+        return (x, pools), None
+
     n_p = jax.tree.leaves(params["stack"])[0].shape[0]
+    unroll = n_p if rt.unroll_scans else 1
     with jax.named_scope("decode/layers"):
-        x, new_subs = jax.lax.scan(
-            period_fn, x, (params["stack"], cache["stack"]),
-            unroll=n_p if rt.unroll_scans else 1)
+        if paged is None:
+            x, new_subs = jax.lax.scan(
+                period_fn, x, (params["stack"], cache["stack"]),
+                unroll=unroll)
+        else:
+            (x, new_subs), _ = jax.lax.scan(
+                paged_period_fn, (x, cache["stack"]),
+                (params["stack"], jnp.arange(n_p, dtype=jnp.int32)),
+                unroll=unroll)
     with jax.named_scope("decode/head"):
         x = blocks.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         head = params.get("lm_head", params["embed"])
@@ -493,7 +518,7 @@ def _attn_prefill_paged(rt: Runtime, p, x, pool_sub, cfg: ModelConfig,
     x: (1, S_loc, D) — the prompt *suffix* (positions ``cached_len ..``),
       SP-sharded contiguously, right-padded to the compile bucket.
     pool_sub: {'k','v'} this shard's page-pool slices
-      (pages_loc, page_size, Hkv, hd) for this layer.
+      (pages_loc, page_size, Hkv, hd_pool) for this layer.
     table_row: (P_sp, W) the slot's full page-table row (static W — the
       suffix prefill runs once per request, so unlike the decode step it
       does not bucket the table width).
@@ -577,10 +602,13 @@ def _attn_prefill_paged(rt: Runtime, p, x, pool_sub, cfg: ModelConfig,
         mine = ((gglob % sp) == rank) \
             & (gidx * ps < prompt_len - cached_len) & (j < W) & (page >= 0)
         page = jnp.where(mine, page, pages_loc)      # OOB -> drop
-        pool_k = pool_sub["k"].at[page].set(kb.astype(pool_sub["k"].dtype),
-                                            mode="drop")
-        pool_v = pool_sub["v"].at[page].set(vb.astype(pool_sub["v"].dtype),
-                                            mode="drop")
+        hd_pool = pool_sub["k"].shape[-1]
+        pool_k = pool_sub["k"].at[page].set(
+            kernels.pad_head(kb, hd_pool).astype(pool_sub["k"].dtype),
+            mode="drop")
+        pool_v = pool_sub["v"].at[page].set(
+            kernels.pad_head(vb, hd_pool).astype(pool_sub["v"].dtype),
+            mode="drop")
     return x, {"k": pool_k, "v": pool_v}
 
 

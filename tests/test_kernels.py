@@ -100,6 +100,23 @@ PAGED_CASES = [
 ]
 
 
+#: how the kernels get the pool: one layer's (pages, ps, Hkv, D) pool
+#: (None), or a stack of 3 layers' pools read at layer 0 or 2
+STACKED = [None, 0, 2]
+
+
+def _stack(pool, layer, seed):
+    """``pool`` as layer ``layer`` of a 3-layer stack whose other layers
+    hold other values, so a read of the wrong layer shows."""
+    if layer is None:
+        return pool
+    rng = np.random.default_rng(seed)
+    layers = [jnp.asarray(rng.normal(size=pool.shape).astype(np.float32))
+              + 10.0 * (i + 1) for i in range(3)]
+    layers[layer] = pool
+    return jnp.stack(layers)
+
+
 def _paged_fixture(B, Hkv, D, ps, W, sp, raggedness, seed=0):
     """Random pools + a table with some -1 holes + per-row cache lengths."""
     rng = np.random.default_rng(seed)
@@ -123,20 +140,24 @@ def _paged_fixture(B, Hkv, D, ps, W, sp, raggedness, seed=0):
     return pool_k, pool_v, jnp.asarray(tbl), jnp.asarray(cl)
 
 
+@pytest.mark.parametrize("layer", STACKED)
 @pytest.mark.parametrize("B,Hq,Hkv,D,ps,W,sp,rank,window,ragged", PAGED_CASES)
 def test_paged_decode_matches_ref(B, Hq, Hkv, D, ps, W, sp, rank, window,
-                                  ragged):
+                                  ragged, layer):
     """Interpret-mode parity: the Pallas paged kernel's partial (o, lse)
     equals ref.block_attention over the dense gather of the same pages
-    (GQA, sliding window, ragged cache_len, partially-filled pages)."""
+    (GQA, sliding window, ragged cache_len, partially-filled pages), from
+    one layer's pool or from a layer of a stacked pool."""
     pool_k, pool_v, tbl, cl = _paged_fixture(B, Hkv, D, ps, W, sp, ragged)
     q = jnp.asarray(np.random.default_rng(1)
                     .normal(size=(B, 1, Hq, D)).astype(np.float32))
     rank_a = jnp.int32(rank)
+    layer_args = () if layer is None else (jnp.int32(layer),)
 
     o_p, lse_p = dispatch.paged_decode(
-        q, pool_k, pool_v, tbl, cl, rank_a, sp=sp, page_size=ps,
-        window=window, impl="pallas")
+        q, _stack(pool_k, layer, 3), _stack(pool_v, layer, 4), tbl, cl,
+        rank_a, *layer_args, sp=sp, page_size=ps, window=window,
+        impl="pallas")
 
     # dense oracle: gather this shard's pages by hand, positions encode
     # validity (invalid slots pushed past the query position)
@@ -161,24 +182,56 @@ def test_paged_decode_matches_ref(B, Hq, Hkv, D, ps, W, sp, rank, window,
     assert (np.asarray(lse_p)[~live] < -1e29).all()
 
 
-def test_paged_decode_ref_impl_matches_oracle():
+@pytest.mark.parametrize("layer", STACKED)
+def test_paged_decode_ref_impl_matches_oracle(layer):
     """dispatch.paged_decode(impl='ref') — the gather fallback — agrees
-    with the pallas kernel bit-for-tolerance on the same fixture."""
+    with the pallas kernel bit-for-tolerance on the same fixture, and both
+    read the layer of a stacked pool that they are given."""
     B, Hq, Hkv, D, ps, W, sp, rank = 2, 4, 2, 16, 4, 3, 2, 1
     pool_k, pool_v, tbl, cl = _paged_fixture(B, Hkv, D, ps, W, sp, "ragged")
     q = jnp.asarray(np.random.default_rng(2)
                     .normal(size=(B, 1, Hq, D)).astype(np.float32))
-    o_r, lse_r = dispatch.paged_decode(q, pool_k, pool_v, tbl, cl,
-                                       jnp.int32(rank), sp=sp, page_size=ps,
+    one_layer = (q, pool_k, pool_v, tbl, cl, jnp.int32(rank))
+    args = one_layer if layer is None else (
+        q, _stack(pool_k, layer, 3), _stack(pool_v, layer, 4), tbl, cl,
+        jnp.int32(rank), jnp.int32(layer))
+    o_r, lse_r = dispatch.paged_decode(*args, sp=sp, page_size=ps,
                                        impl="ref")
-    o_p, lse_p = dispatch.paged_decode(q, pool_k, pool_v, tbl, cl,
-                                       jnp.int32(rank), sp=sp, page_size=ps,
+    o_p, lse_p = dispatch.paged_decode(*args, sp=sp, page_size=ps,
                                        impl="pallas")
     np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_r),
                                atol=2e-5, rtol=2e-5)
     live = np.asarray(lse_r) > -1e29
     np.testing.assert_allclose(np.asarray(lse_p)[live],
                                np.asarray(lse_r)[live], atol=1e-4, rtol=1e-4)
+    # the stacked read equals the read of that layer's pool alone
+    o_1, lse_1 = dispatch.paged_decode(*one_layer, sp=sp, page_size=ps,
+                                       impl="ref")
+    np.testing.assert_array_equal(np.asarray(o_r), np.asarray(o_1))
+    np.testing.assert_array_equal(np.asarray(lse_r), np.asarray(lse_1))
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_paged_decode_reads_lane_padded_pool(impl):
+    """The engine's pools carry the head dim zero-padded to whole lane
+    tiles (``paged_cache.pool_head_dim``); dispatch reads such a pool as
+    the unpadded one, and returns q's head dim."""
+    B, Hq, Hkv, D, ps, W, sp, rank = 2, 4, 2, 16, 4, 3, 2, 1
+    pool_k, pool_v, tbl, cl = _paged_fixture(B, Hkv, D, ps, W, sp, "ragged")
+    q = jnp.asarray(np.random.default_rng(2)
+                    .normal(size=(B, 1, Hq, D)).astype(np.float32))
+    pad = [(0, 0)] * 3 + [(0, 128 - D)]
+    kw = dict(sp=sp, page_size=ps, window=6, impl=impl)
+    o, lse = dispatch.paged_decode(q, jnp.pad(pool_k, pad),
+                                   jnp.pad(pool_v, pad), tbl, cl,
+                                   jnp.int32(rank), **kw)
+    o_1, lse_1 = dispatch.paged_decode(q, pool_k, pool_v, tbl, cl,
+                                       jnp.int32(rank), **kw)
+    assert o.shape == o_1.shape == q.shape
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_1),
+                               atol=2e-6, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_1),
+                               atol=2e-6, rtol=2e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ so it must never happen while a module is imported. Keep these tests in
 this one file.
 """
 
+import functools
 import os
 
 import jax
@@ -25,6 +26,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import paged_decode, paged_prefill
 
 HQ, HKV, D, WINDOW = 32, 8, 80, 4096     # h2o-danube-1.8b
+POOL_D = 128                             # D padded to whole lane tiles
 PAGE, PAGES = 16, 512
 
 
@@ -79,16 +81,21 @@ def _bwd(ragged):
     return fn, args
 
 
-def _paged_decode():
+def _paged_decode(layers=None):
+    """One layer's pool, or — as the decode step hands it over — the pools
+    of ``layers`` layers stacked, read at a traced layer index, with the
+    head dim padded to the lane tile (q padded to match)."""
     B, W = 8, 64
-    args = [((B, 1, HQ, D), jnp.bfloat16),
-            ((PAGES, PAGE, HKV, D), jnp.bfloat16),
-            ((PAGES, PAGE, HKV, D), jnp.bfloat16), ((B, W), jnp.int32),
-            ((B,), jnp.int32), ((), jnp.int32)]
+    d = D if layers is None else POOL_D
+    pool = (PAGES, PAGE, HKV, d) if layers is None else \
+        (layers, PAGES, PAGE, HKV, d)
+    args = [((B, 1, HQ, d), jnp.bfloat16), (pool, jnp.bfloat16),
+            (pool, jnp.bfloat16), ((B, W), jnp.int32),
+            ((B,), jnp.int32), ((), jnp.int32), ((), jnp.int32)]
 
-    def fn(q, pk, pv, table, cache_len, rank):
+    def fn(q, pk, pv, table, cache_len, rank, layer):
         return paged_decode.paged_decode_attention(
-            q, pk, pv, table, cache_len, rank, sp=1, page_size=PAGE,
+            q, pk, pv, table, cache_len, rank, layer, sp=1, page_size=PAGE,
             window=WINDOW, interpret=False)
     return fn, args
 
@@ -114,6 +121,7 @@ CASES = {
     "flash_bwd_ragged": lambda: _bwd(ragged=True),
     "ragged_prefill_fwd": lambda: _fwd(merge=False, ragged=True),
     "paged_decode": _paged_decode,
+    "paged_decode_stacked": lambda: _paged_decode(layers=24),
     "paged_prefill": _paged_prefill,
     "flash_fwd_d128": lambda: _fwd(merge=True, ragged=False, d=128),
 }
@@ -126,3 +134,75 @@ def test_kernel_compiles_for_v5e(one_chip, case):
               for s, d in args]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_step_updates_pool_in_place(one_chip, monkeypatch):
+    """The engine's decode step at h2o-danube head widths (2 layers, 512
+    pages), compiled for a described v5e, touches the KV pool only where
+    it must: no copy, dynamic-slice or dynamic-update-slice anywhere in
+    the program yields a layer's pool slice or the whole pool (the layer
+    scan carries the stacked pool; the token scatter and the kernel index
+    it in place), the donated pools alias the outputs, and the program
+    needs less scratch memory than one layer's pool slice."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import registry
+    from repro.dist.sharding import SP_AXES
+    from repro.engine import engine, paged_cache
+    from repro.models.factory import build_model
+    from repro.plan import make_serve_plan
+
+    # this process is on the CPU, where the kernel defaults to interpret
+    # mode; the described chip needs the real lowering
+    compiled_kernel = functools.partial(paged_decode.paged_decode_attention,
+                                        interpret=False)
+    monkeypatch.setattr(paged_decode, "paged_decode_attention",
+                        compiled_kernel)
+    B, W, layers = 8, 64, 2
+    cfg = dataclasses.replace(registry.get("h2o-danube-1.8b"),
+                              num_layers=layers)
+    model = build_model(cfg)
+    plan = make_serve_plan(cfg, n_devices=1, decode_batch=B,
+                           page_size=PAGE, max_len=W * PAGE,
+                           kernel_impl="pallas", block_impl="pallas")
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1, 1, 1),
+                ("data",) + SP_AXES)
+    param_specs = model.partition(plan.run_config().sharding_rules)
+    fn = engine.decode_step_fn(cfg, engine.serve_runtime(model, plan), mesh,
+                               param_specs, PAGE)
+
+    def on(spec, a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params = jax.tree.map(on, param_specs, model.abstract(),
+                          is_leaf=lambda x: isinstance(x, P))
+    pools = jax.tree.map(on, paged_cache.pool_partition(cfg),
+                         paged_cache.pool_spec(cfg, PAGES, PAGE),
+                         is_leaf=lambda x: isinstance(x, P))
+    small = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P()))
+    compiled = fn.lower(
+        params, pools, small((B, 1), jnp.int32), small((B,), jnp.int32),
+        small((B, 1, W), jnp.int32), small((B,), jnp.float32),
+        small((B,), jnp.int32), small((B,), jnp.float32),
+        small((B, 2), jnp.uint32), small((B,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+
+    assert "tpu_custom_call" in hlo          # the kernel, not interpreted
+    slice_shape = f"{PAGES},{PAGE},{HKV},{POOL_D}]"
+    moves = re.compile(r"=\s*\(?bf16\[(?:\d+,)?" + re.escape(slice_shape)
+                       + r"[^=]*?\s(copy|copy-start|dynamic-slice|"
+                       r"dynamic-update-slice)\(")
+    bad = [line.strip()[:160] for line in hlo.splitlines()
+           if moves.search(line)]
+    assert not bad, "pool moved:\n" + "\n".join(bad)
+    stats = compiled.memory_analysis()
+    layer_bytes = PAGES * PAGE * HKV * POOL_D * 2
+    assert stats.alias_size_in_bytes >= 2 * layers * layer_bytes
+    assert stats.temp_size_in_bytes < layer_bytes

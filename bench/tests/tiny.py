@@ -24,6 +24,23 @@ TINY_SERVE = {
     "check": {"max_requests": 16, "min_tokens": 150},
     "limits": {"served_gap": 1e-3}}
 
+TINY_TRAIN = {
+    "kind": "train", "seq_len": 64, "global_batch": 1, "microbatches": 1,
+    "plan": {"scheme": "startrail", "c": 2, "r": 1, "sp_size": 4,
+             "placement": "team_inner", "seq_scheme": "zigzag"},
+    "kernels": "pallas",
+    "optimizer": {"learning_rate": 2e-3, "b1": 0.9, "b2": 0.95,
+                  "eps": 1e-8, "weight_decay": 0.1, "grad_clip": 1.0,
+                  "warmup_steps": 0, "decay_steps": 1000,
+                  "min_lr_ratio": 0.1, "state_dtype": "float32"},
+    "trace_seconds": 1, "check": {"steps": 3, "qblock": 16},
+    "limits": {"train_loss_gap": 1e-4, "grad_leaf_gap": 1e-3,
+               "change_leaf_gap": 1e-3}}
+
+#: each benchmark cell's tiny stand-in
+TINY_CELLS = {"serve.danube.decode": "tiny.serve",
+              "train.danube.p4.startrail": "tiny.train"}
+
 
 def make_tiny_root(tmp: pathlib.Path) -> pathlib.Path:
     """A checkout-like tree whose BENCHMARK.json names tiny cells, with the
@@ -35,15 +52,18 @@ def make_tiny_root(tmp: pathlib.Path) -> pathlib.Path:
     (bench / "traffic").mkdir()
     (bench / "configs" / "tiny.json").write_text(json.dumps(TINY_CONF))
     (bench / "traffic" / "tiny_serve.json").write_text(json.dumps(TINY_SERVE))
+    (bench / "traffic" / "tiny_train.json").write_text(json.dumps(TINY_TRAIN))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec["configs"] = [{"name": "tiny", "source": "tiny",
                         "file": "bench/configs/tiny.json", "reduced": [],
                         "why": "tiny"}]
     spec["workloads"] = [
         {"name": "tiny.serve", "config": "tiny", "traffic": "tiny_serve",
-         "chips": 1, "why": "tiny"}]
+         "chips": 1, "why": "tiny"},
+        {"name": "tiny.train", "config": "tiny", "traffic": "tiny_train",
+         "chips": 4, "why": "tiny"}]
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = ["tiny.serve"]
+            m["workloads"] = [TINY_CELLS[w] for w in m["workloads"]]
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp

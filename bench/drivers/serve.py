@@ -1,17 +1,30 @@
-"""Serving driver: a closed loop of clients over the program's ``Engine``.
+"""Serving driver: a closed loop of clients, or an open loop of
+arrivals, over the program's ``Engine``.
 
 Set-up builds the engine on the seed's weights, warms every prefill bucket
-and decode width the run's requests reach (and no other), resets the
-engine, sends each client's first request (which starts mid-stream, see
-``workload.serve_requests``) and runs the engine until every one of them
-is decoding. The window then drives ``Engine.step()``: each
-(uid, token) pair it returns is stamped with the host clock when the step
-returns; a client whose request has all its tokens sends its next one at
-once. The window closes on the first step that ends past ``--seconds``.
+and decode width the run's requests reach (and no other) and resets the
+engine. A closed loop then sends each client's first request (which
+starts mid-stream, see ``workload.serve_requests``) and runs the engine
+until every one of them is decoding. The window then drives
+``Engine.step()``: each (uid, token) pair it returns is stamped with the
+host clock when the step returns. In a closed loop a client whose request
+has all its tokens sends its next one at once; an open loop sends each
+request at its time in ``workload.serve_arrivals``, counted from
+``lead_seconds`` before the window opens (so that the window opens on the
+queue's steady state, not on an empty engine), and sleeps while the engine
+has nothing to do. The window closes on the first step that ends past
+``--seconds``.
 
 Every emitted token in the window counts towards ``serve_tokens_per_s``;
 every gap between two tokens of one request that ends in the window is an
-inter-token sample (``itl_p95_ms``).
+inter-token sample (``itl_p95_ms``); every request whose first token is
+emitted in the window gives a time-to-first-token sample
+(``ttft_p50_ms``), from when it was sent (``Loop.submit``: a closed
+loop's call, an open loop's due time) to the return of the step that
+emitted that token; every request whose last token is emitted in the
+window gives a sample of the time to the whole answer
+(``answer_p50_ms``), from when it was sent to the return of the step
+that emitted its last token.
 
 After the window: the engine is freed, and the reference runs over a
 sample of the served requests drawn from the seed, the longest among
@@ -23,11 +36,15 @@ from __future__ import annotations
 
 import math
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from bench import harness, reference, workload
+from bench.trace import IDLE
+
+TOO_FEW = ("the traffic file holds too few requests for this window; "
+           "raise its 'requests'")
 
 
 def build(cell, seed: int, devs, tracer=None):
@@ -98,26 +115,44 @@ def warm_up(eng, reqs: List[workload.Req]) -> Dict:
 
 
 class Loop:
-    """The closed loop's clients and the samples they see."""
+    """The clients, closed loop or open, and the samples they see.
 
-    def __init__(self, eng, reqs: List[workload.Req]):
+    ``arrivals``: each request's send time in seconds after ``origin``
+    (an open loop), or None (a closed loop). Samples are taken once
+    ``t0``, the window's start, is set."""
+
+    def __init__(self, eng, reqs: List[workload.Req],
+                 arrivals: Optional[List[float]] = None):
         self.eng, self.reqs, self.next = eng, reqs, 0
+        self.arrivals = arrivals
         self.recs: Dict[str, Dict] = {}
         self.failed = 0
-        self.t0 = None
+        self.t0 = self.origin = None
+        self.in_flight = self.peak_in_flight = 0
         self.gaps: List[float] = []
+        self.ttft: Dict[str, float] = {}
+        self.answer: List[float] = []
+        self.late: List[float] = []
         self.tokens = 0
         self.prefill_lens: List[int] = []
+        self.prefill_buckets: List[int] = []
         self.decode_rows: List[List[int]] = []
         self.step_s: List[float] = []
         self.prefill_steps = 0
 
-    def submit(self) -> None:
+    def submit(self, sent: Optional[float] = None) -> None:
+        """Send the next request; its time-to-first-token counts from
+        ``sent`` (by default, now), and how late after ``sent`` it goes
+        is kept (``send_late_ms_max``)."""
         from repro.engine.scheduler import Request
 
+        now = time.perf_counter()
+        if sent is None:
+            sent = now
+        else:
+            self.late.append(now - sent)
         if self.next >= len(self.reqs):
-            raise RuntimeError("the traffic file holds too few requests for "
-                               "this window; raise its 'requests'")
+            raise RuntimeError(TOO_FEW)
         r = self.reqs[self.next]
         self.next += 1
         rej = self.eng.add_request(Request(uid=r.uid, tokens=r.prompt,
@@ -125,8 +160,35 @@ class Loop:
         if rej is not None:
             self.failed += 1
             return
-        self.recs[r.uid] = {"req": r, "first": None,
+        self.recs[r.uid] = {"req": r, "sent": sent, "first": None,
                             "last": None, "out": []}
+        self.in_flight += 1
+        if self.t0 is not None:
+            self.peak_in_flight = max(self.peak_in_flight, self.in_flight)
+
+    def run_until(self, end: float) -> None:
+        while time.perf_counter() < end:
+            self.tick(end)
+
+    def tick(self, end: float) -> None:
+        """One turn of the window: an open loop first sends every request
+        that has come due, and sleeps until the next one (or ``end``)
+        while the engine has nothing to do; then one engine step."""
+        if self.arrivals is not None:
+            arr, now = self.arrivals, time.perf_counter()
+            t = self.origin
+            while self.next < len(arr) and t + arr[self.next] <= now:
+                self.submit(t + arr[self.next])
+            if self.eng.idle():
+                if self.next >= len(arr):
+                    raise RuntimeError(TOO_FEW)
+                from jax.profiler import TraceAnnotation
+
+                with TraceAnnotation(IDLE):
+                    time.sleep(max(0.0, min(t + arr[self.next], end)
+                                   - now))
+                return
+        self.step()
 
     def step(self) -> None:
         eng = self.eng
@@ -140,10 +202,14 @@ class Loop:
             self.prefill_steps += bool(eng.last_step_prefills)
             self.prefill_lens.extend(e - s for s, e in
                                      eng.last_step_prefills)
+            self.prefill_buckets.extend(eng._prefill_bucket(e - s) for s, e
+                                        in eng.last_step_prefills)
         for uid, tok in emitted:
             rec = self.recs[uid]
             if rec["first"] is None:
                 rec["first"] = now
+                if measuring:
+                    self.ttft[uid] = now - rec["sent"]
             elif measuring:
                 self.gaps.append(now - rec["last"])
                 # a decode row: the previous token sat at this position
@@ -154,7 +220,11 @@ class Loop:
                 self.tokens += 1
             if len(rec["out"]) == rec["req"].max_new:
                 rec["done"] = now
-                self.submit()
+                self.in_flight -= 1
+                if measuring:
+                    self.answer.append(now - rec["sent"])
+                if self.arrivals is None:
+                    self.submit()
         if rows:
             self.decode_rows.append(rows)
 
@@ -162,6 +232,7 @@ class Loop:
         """What the window has counted so far (for a traced part)."""
         return {"decode_rows": list(self.decode_rows),
                 "prefill_lens": list(self.prefill_lens),
+                "prefill_buckets": list(self.prefill_buckets),
                 "tokens": self.tokens}
 
 
@@ -217,12 +288,16 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     vocab = cell.conf["vocab_size"]
     reqs = workload.serve_requests(tr, vocab, seed)
     warm = warm_up(eng, reqs)
-    loop = Loop(eng, reqs)
-    for _ in range(int(tr["clients"])):
-        loop.submit()
-    while eng.scheduler.queue or any(
-            st.cache_len == 0 for st in eng.scheduler.active()):
-        loop.step()
+    loop = Loop(eng, reqs, workload.serve_arrivals(tr))
+    if loop.arrivals is None:
+        for _ in range(int(tr["clients"])):
+            loop.submit()
+        while eng.scheduler.queue or any(
+                st.cache_len == 0 for st in eng.scheduler.active()):
+            loop.step()
+    else:
+        loop.origin = time.perf_counter()
+        loop.run_until(loop.origin + float(tr.get("lead_seconds", 0.0)))
 
     # a traced run traces the first ``trace_seconds`` of the same window,
     # and its facts are counted over that part alone
@@ -237,13 +312,11 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     facts_at = None
     if trace:
         with TraceAnnotation("bench/window"):
-            while time.perf_counter() - t0 < traced:
-                loop.step()
+            loop.run_until(t0 + traced)
         facts_at = (time.perf_counter() - t0, loop.snapshot(),
                     eng.metrics.to_dict())
         jax.profiler.stop_trace()
-    while time.perf_counter() - t0 < seconds:
-        loop.step()
+    loop.run_until(t0 + seconds)
     t1 = time.perf_counter()
     window_s = t1 - t0
     m1 = eng.metrics.to_dict()
@@ -257,8 +330,10 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     check = tr["check"]
     picked = sample_served(recs, check, seed)
     arch = reference.Arch.from_config(cell.conf)
+    t_ref = time.perf_counter()
     gaps = reference.served_gaps(
         arch, seed, [(r["req"].prompt, r["out"]) for r in picked])
+    reference_s = time.perf_counter() - t_ref
     limits = tr["limits"]
     served_gap = max(gaps) if gaps else float("inf")
     checks = {"served_gap": (served_gap, limits["served_gap"])}
@@ -269,9 +344,10 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             arch, seed, [(r["req"].prompt, r["out"]) for r in picked],
             control=True)
         control = max(ctl) if ctl else float("inf")
+        lim = limits["served_gap"]
         readings = {"served_gap": {
             "program": served_gap, "control": control,
-            "control_fails": not control <= limits["served_gap"]}}
+            "control_fails": None if lim is None else not control <= lim}}
     conf = cell.conf
     window = conf.get("sliding_window")
     rows = [q for step in loop.decode_rows for q in step]
@@ -286,13 +362,31 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                  "window_s": t_traced, **snap,
                  "decode_steps": mt["decode_steps"] - m0["decode_steps"],
                  "occupancy_sum": mt["occupancy_sum"] - m0["occupancy_sum"]}
+    ttft = list(loop.ttft.values())
     e2e = {"serve_tokens_per_s": loop.tokens / window_s,
-           "itl_p95_ms": 1e3 * harness.percentile(loop.gaps, 95)}
+           "itl_p95_ms": 1e3 * harness.percentile(loop.gaps, 95),
+           "ttft_p50_ms": 1e3 * harness.percentile(ttft, 50),
+           "answer_p50_ms": 1e3 * harness.percentile(loop.answer, 50)}
     return {"setup_s": t0 - t_start, "e2e": e2e, "attempted": attempted,
             "failed": failed, "checks": checks,
             "memory_peak_bytes": peak, "window_s": window_s,
             "trace_dir": trace_dir, "facts": facts,
             "notes": {"gaps": len(loop.gaps), "tokens": loop.tokens,
+                      "ttft_samples": len(ttft),
+                      "answer_samples": len(loop.answer),
+                      "itl_p95_ms": 1e3 * harness.percentile(loop.gaps, 95)
+                      if loop.gaps else None,
+                      "peak_in_flight": loop.peak_in_flight,
+                      "peak_pages": m1["peak_pages"],
+                      "pages_total": m1["pages_total"],
+                      "send_late_ms_max": 1e3 * max(loop.late, default=0.0),
+                      "ttft_p90_ms": 1e3 * harness.percentile(ttft, 90)
+                      if ttft else None,
+                      "sent_unanswered": sum(r["first"] is None
+                                             for r in recs),
+                      "longest_context": max(len(r["req"].prompt)
+                                             + r["req"].max_new
+                                             for r in recs),
                       "finished": sum("done" in r for r in recs),
                       "window_steps": len(steps_ms),
                       "prefill_steps": loop.prefill_steps,
@@ -301,6 +395,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                       "rows_past_window": past_window(rows, window)
                       / max(len(rows), 1),
                       "checked_requests": len(picked),
+                      "reference_seconds": reference_s,
                       "checked_tokens": sum(len(r["out"]) for r in picked),
                       "checked_rows_past_window": past_window(checked_rows,
                                                              window),

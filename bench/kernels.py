@@ -17,3 +17,9 @@ def _base(name: str) -> str:
 
 def is_paged_decode(name: str) -> bool:
     return _base(name) == "paged_decode_attention"
+
+
+def is_flash_fwd(name: str) -> bool:
+    """The flash forward kernel: a prefill's attention (on the chip
+    ``flash_attention_fwd.<n>``)."""
+    return _base(name) == "flash_attention_fwd"

@@ -38,6 +38,33 @@ def test_cell_resolves_by_name(cell):
         assert m["moves"] in e2e
 
 
+PREPARED = sorted((ROOT / "bench" / "prepared").glob("*.json"))
+
+
+@pytest.mark.parametrize("path", PREPARED, ids=lambda p: p.stem)
+def test_prepared_cell_resolves_by_name(path, tmp_path):
+    """A cell prepared under bench/prepared/ (its files here, its entries
+    not yet in BENCHMARK.json, their bounds unset) resolves to its files
+    once the entries join BENCHMARK.json."""
+    frag = json.loads(path.read_text())
+    assert [w["name"] for w in frag["workloads"]] == [path.stem]
+    spec = json.loads(json.dumps(SPEC))
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert not {m["name"] for m in frag[key]} & {
+            m["name"] for m in spec[key]}
+        spec[key] += frag[key]
+    assert all(m["bound"] is None for m in frag["end_to_end"])
+    for c in frag["configs"]:
+        conf = json.loads((ROOT / c["file"]).read_text())
+        assert conf["reduced"] == c["reduced"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    (tmp_path / "bench").symlink_to(ROOT / "bench")
+    c = harness.load_cell(tmp_path, path.stem)
+    e2e = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert c.per_layer and all(m["moves"] in e2e for m in c.per_layer)
+
+
 def test_names_units_and_bounds():
     names = [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
     names += CELLS + [c["name"] for c in SPEC["configs"]]

@@ -25,9 +25,11 @@ import pathlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW = "bench/window"
+#: an open loop waiting, with nothing in the engine, for its next arrival
+IDLE = "bench/idle"
 #: host spans that label idle gaps (the innermost one around a gap wins)
 HOST_SPANS = ("engine/prefill", "engine/decode", "engine/step",
-              "train/data", "train/step")
+              "train/data", "train/step", IDLE)
 OPS_LINE = "XLA Ops"
 
 
@@ -139,6 +141,29 @@ class Reduced:
 
     def idle_shares(self) -> List[float]:
         return [1.0 - d.busy_s() / d.window_s for d in self.devices]
+
+    def _under(self, d: Device, span: str) -> List[Tuple[int, int]]:
+        """The parts of ``d``'s window under the host span ``span``."""
+        w0, w1 = d.window
+        return _union([(max(s, w0), min(e, w1)) for s, e, n in self.host
+                       if n == span and e > w0 and s < w1])
+
+    def engaged_s(self, span: str = IDLE) -> float:
+        """The window less the time under ``span``, mean over devices:
+        the time the system had work."""
+        return sum(d.window_s - _length(self._under(d, span)) * 1e-9
+                   for d in self.devices) / len(self.devices)
+
+    def engaged_idle_shares(self, span: str = IDLE) -> List[float]:
+        """Per device, the idle time not under ``span`` over the window
+        less the time under ``span``."""
+        out = []
+        for d in self.devices:
+            under = self._under(d, span)
+            engaged = d.window_s - _length(under) * 1e-9
+            idle = _length(_subtract(d.gaps(), under)) * 1e-9
+            out.append(idle / engaged if engaged > 0 else float("nan"))
+        return out
 
     def op_seconds(self, match) -> float:
         """Device self seconds, summed over devices, of the operations
